@@ -353,13 +353,7 @@ class HyperPlan:
 def _require_host_memory(plan: HyperPlan) -> None:
     """Raise HostMemoryError unless the backend has a host memory tier."""
     import jax
-    try:
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-    except Exception:  # noqa: BLE001 - very old jax: no memories API
-        raise HostMemoryError(
-            "plan requests host offload (params_on_host/opt_state_on_host/"
-            "activation_offload) but this JAX backend exposes no memory-kind "
-            "API; drop the offload intent or upgrade JAX")
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
     if not any(k.endswith("host") for k in kinds):
         raise HostMemoryError(
             "plan requests host offload but the backend has no host memory "

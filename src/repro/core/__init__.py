@@ -1,7 +1,3 @@
 # The paper's primary contribution — implement the SYSTEM here
 # (scheduler, optimizer, data path, serving loop, etc.) in the
 # host framework. Add sibling subpackages for substrates.
-
-# Installing the JAX version-compat shims must happen before any sibling
-# module (or test snippet) touches jax.shard_map / jax.sharding.AxisType.
-from repro.core import compat as _compat  # noqa: E402,F401

@@ -1,93 +1,29 @@
-"""Version-compat shims for JAX API moves.
+"""Memory-kind names of the installed backend.
 
-The repo is written against the modern JAX surface (``jax.shard_map``,
-``check_vma=``, ``jax.sharding.AxisType``); older installs (<= 0.4.x)
-spell these ``jax.experimental.shard_map.shard_map``, ``check_rep=`` and
-have no axis types at all.  This module papers over the difference:
-
-  - ``from repro.core.compat import shard_map`` works on both sides and
-    translates the ``check_vma``/``check_rep`` kwarg to whatever the
-    installed jax understands;
-  - importing this module (``repro.core`` does it automatically) installs
-    forward-compat aliases ``jax.shard_map``, ``jax.sharding.AxisType``
-    and an ``axis_types=``-tolerant ``jax.make_mesh``, so call sites and
-    test snippets written for new JAX run unmodified on old JAX.
+TPU and GPU backends expose ``pinned_host`` for the host tier; a backend
+with only ``unpinned_host`` uses that (host placement is then a no-op,
+but the plumbing still runs).
 """
 from __future__ import annotations
 
-import enum
 import functools
-import inspect
 
 import jax
-
-_raw_shard_map = getattr(jax, "shard_map", None)
-if _raw_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-_SM_PARAMS = set(inspect.signature(_raw_shard_map).parameters)
-
-
-@functools.wraps(_raw_shard_map)
-def shard_map(f, *args, **kw):
-    if "check_vma" in kw and "check_vma" not in _SM_PARAMS:
-        kw["check_rep"] = kw.pop("check_vma")
-    elif "check_rep" in kw and "check_rep" not in _SM_PARAMS:
-        kw["check_vma"] = kw.pop("check_rep")
-    return _raw_shard_map(f, *args, **kw)
-
-
-def _install_forward_compat() -> None:
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = shard_map
-    if not hasattr(jax.sharding, "AxisType"):
-        class AxisType(enum.Enum):
-            Auto = "auto"
-            Explicit = "explicit"
-            Manual = "manual"
-        jax.sharding.AxisType = AxisType
-    if not hasattr(jax, "make_mesh"):
-        return                               # pre-0.4.35: nothing to wrap
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        _raw_make_mesh = jax.make_mesh
-
-        @functools.wraps(_raw_make_mesh)
-        def make_mesh(axis_shapes, axis_names, *, axis_types=None, **kw):
-            del axis_types               # old jax: all axes are Auto anyway
-            return _raw_make_mesh(axis_shapes, axis_names, **kw)
-
-        jax.make_mesh = make_mesh
-
-
-_install_forward_compat()
 
 
 @functools.lru_cache(maxsize=None)
 def host_memory_kind() -> str:
-    """The platform's host-tier memory kind.
-
-    TPU/GPU backends expose ``pinned_host``; the CPU backend only has
-    ``unpinned_host`` (which is also its default memory — host placement
-    degenerates to a no-op there, but the plumbing still runs).
-    """
-    try:
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-    except Exception:  # noqa: BLE001 - very old jax: no memories API
+    """The platform's host-tier memory kind."""
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
+    if "pinned_host" in kinds or "unpinned_host" not in kinds:
         return "pinned_host"
-    if "pinned_host" in kinds:
-        return "pinned_host"
-    if "unpinned_host" in kinds:
-        return "unpinned_host"
-    return "pinned_host"
+    return "unpinned_host"
 
 
 @functools.lru_cache(maxsize=None)
 def device_memory_kind() -> str:
     """The accelerator-resident (default) memory kind ("device" on TPU/GPU)."""
-    try:
-        return jax.devices()[0].default_memory().kind
-    except Exception:  # noqa: BLE001
-        return "device"
+    return jax.devices()[0].default_memory().kind
 
 
-__all__ = ["shard_map", "host_memory_kind", "device_memory_kind"]
+__all__ = ["host_memory_kind", "device_memory_kind"]

@@ -23,7 +23,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 
 @dataclasses.dataclass
@@ -38,11 +39,9 @@ class HostArchive:
 
     One placement policy shared by every cold-KV consumer
     (:class:`KVCachePool`'s block archive, HyperServe's preempted-request
-    page spill): arrays ``put`` here move to ``pinned_host`` memory when
-    the mesh exposes it, and come back to device on ``fetch``.  On hosts
-    whose backend has no host memory kind (the CPU test container) the
-    placement is a no-op but the accounting — what the serving runtime
-    budgets against — still works.
+    page spill): arrays ``put`` here move to the backend's host memory kind
+    (``pinned_host`` on TPU) — on the mesh's devices, or without a mesh on
+    the default device — and come back to device memory on ``fetch``.
 
     Since HyperMem the archive is **bounded**: storage is a
     :class:`~repro.mem.tiers.TierStack`, so the host tier spills LRU
@@ -58,19 +57,20 @@ class HostArchive:
                  obs=None):
         from repro.core.compat import device_memory_kind, host_memory_kind
         from repro.mem.tiers import TierStack
-        self._host = None
-        self._dev = None
         if mesh is not None:
-            try:
-                self._host = NamedSharding(mesh, P(),
-                                           memory_kind=host_memory_kind())
-                # explicit device-tier destination: a bare device_put is the
-                # identity for an array already committed to pinned_host
-                self._dev = NamedSharding(mesh, P(),
-                                          memory_kind=device_memory_kind())
-            except (ValueError, TypeError):   # backend without memory kinds
-                self._host = None
-                self._dev = None
+            self._host = NamedSharding(mesh, P(),
+                                       memory_kind=host_memory_kind())
+            # explicit device-tier destination: a bare device_put is the
+            # identity for an array already committed to pinned_host
+            self._dev = NamedSharding(mesh, P(),
+                                      memory_kind=device_memory_kind())
+        else:
+            # one device, no mesh: the default device's host memory
+            dev = jax.devices()[0]
+            self._host = SingleDeviceSharding(dev,
+                                              memory_kind=host_memory_kind())
+            self._dev = SingleDeviceSharding(dev,
+                                             memory_kind=device_memory_kind())
         self._tiers = TierStack(host_budget_bytes, disk_budget_bytes)
         self._obs = obs
         self._seen = dict(self._tiers.counters)
@@ -88,15 +88,11 @@ class HostArchive:
 
     # -- placement ---------------------------------------------------------
     def to_host(self, x):
-        if self._host is not None:
-            return jax.tree.map(lambda a: jax.device_put(a, self._host), x)
-        return x
+        return jax.tree.map(lambda a: jax.device_put(a, self._host), x)
 
     def to_device(self, x, sharding=None):
         dst = sharding if sharding is not None else self._dev
-        if dst is not None:
-            return jax.tree.map(lambda a: jax.device_put(a, dst), x)
-        return x
+        return jax.tree.map(lambda a: jax.device_put(a, dst), x)
 
     # -- keyed store (spilled pages, archived blocks) ----------------------
     def put(self, key, value, *, pinned: bool = True) -> None:
@@ -123,6 +119,11 @@ class HostArchive:
 
     def tier_of(self, key) -> Optional[str]:
         return self._tiers.tier_of(key)
+
+    def host_memory_kinds(self) -> set:
+        """Memory kinds of the arrays held in the host tier right now."""
+        return {a.sharding.memory_kind
+                for v in self._tiers.host_values() for a in jax.tree.leaves(v)}
 
     @property
     def counters(self) -> dict:
@@ -214,7 +215,7 @@ class KVCachePool:
         a, l = _partial_attn(q, self.hot_k[:, :n_hot], self.hot_v[:, :n_hot])
         accs.append(a); lses.append(l)
         for kb, vb in zip(self.archive_k, self.archive_v):
-            kd, vd = jax.device_put((kb, vb))      # stream block to device
+            kd, vd = self._archive.to_device((kb, vb))   # stream to device
             a, l = _partial_attn(q, kd, vd)
             accs.append(a); lses.append(l)
         return combine_partials(accs, lses).astype(q.dtype)

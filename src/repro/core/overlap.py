@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +184,7 @@ def ep_moe_shardmap(p, x, cfg, mesh: Mesh, *, ep_axis: str = "model",
     pspec = {k: (P(ep_axis, None, None) if k in ("w_gate", "w_up", "w_down")
                  else P()) for k in p}
     psub = {k: p[k] for k in pspec}
-    return shard_map(local_fn, mesh=mesh,
+    return jax.shard_map(local_fn, mesh=mesh,
                      in_specs=(pspec, P(dp, None, None)),
                      out_specs=P(dp, None, None),
                      check_vma=False)(psub, x)
@@ -272,7 +271,7 @@ def moe_dp_local(p, x3, idx3, gates3, cfg, mesh, *, tp_axis: str = "model"):
     wspec_up = P(e_entry, None, tp_axis if has_tp else None)
     wspec_dn = P(e_entry, tp_axis if has_tp else None, None)
     tok = P(dp_entry, tp_axis if has_tp else None, None)
-    return shard_map(local_fn, mesh=mesh,
+    return jax.shard_map(local_fn, mesh=mesh,
                      in_specs=(wspec_up, wspec_up, wspec_dn, tok, tok, tok),
                      out_specs=tok,
                      check_vma=False)(

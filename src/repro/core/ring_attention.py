@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 
 from repro.kernels import ref
 
@@ -61,7 +60,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = "model",
             step, (acc, m, l, kl, vl), jnp.arange(n))
         return ref.flash_finalize(acc, l, ql.dtype)
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)(q, k, v)
 
 

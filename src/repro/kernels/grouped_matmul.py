@@ -3,9 +3,9 @@
 ``out[t] = x[t] @ w[expert_of(t)]`` for ``x`` sorted by expert with
 ``group_sizes`` giving each expert's contiguous row count.
 
-Grid: (num_token_tiles, E) with the expert dim innermost so each output
-tile accumulates across its (at most two) overlapping experts and is then
-final — the canonical TPU accumulation pattern.  Group offsets arrive via
+Grid: (num_token_tiles, num_F_tiles, E) with the expert dim innermost so
+each output tile accumulates across its (at most two) overlapping experts
+and is then final — the canonical TPU accumulation pattern.  Group offsets arrive via
 scalar prefetch (SMEM); (tile, expert) pairs with no row overlap are
 skipped with ``pl.when``, so MXU work is proportional to actual tokens,
 not E*T.
@@ -19,17 +19,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _block(n, want):
-    b = min(want, n)
-    while n % b:
-        b //= 2
-    return max(b, 1)
+from repro.kernels.tiling import block
 
 
 def _kernel(offs_ref, x_ref, w_ref, o_ref, acc_ref, *, bt, E):
     t = pl.program_id(0)
-    e = pl.program_id(1)
+    e = pl.program_id(2)
 
     @pl.when(e == 0)
     def _init():
@@ -42,7 +37,7 @@ def _kernel(offs_ref, x_ref, w_ref, o_ref, acc_ref, *, bt, E):
     @pl.when(jnp.logical_and(start < t0 + bt, end > t0))
     def _compute():
         x = x_ref[...]                                # (bt, D)
-        w = w_ref[0]                                  # (D, F)
+        w = w_ref[0]                                  # (D, bf)
         rows = t0 + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
         mask = (rows >= start) & (rows < end)         # (bt, 1)
         xm = jnp.where(mask, x, 0)
@@ -56,25 +51,30 @@ def _kernel(offs_ref, x_ref, w_ref, o_ref, acc_ref, *, bt, E):
 
 
 def grouped_matmul(x, w, group_sizes, *, block_t: int = 256,
-                   interpret: bool = False):
+                   block_f: int = 512, interpret: bool = False):
     """x: (T, D) sorted by expert; w: (E, D, F); group_sizes: (E,) -> (T, F)."""
     T, D = x.shape
     E, _, F = w.shape
-    bt = _block(T, block_t)
+    bt = block(T, block_t)
     nt = T // bt
+    # an expert's whole (D, F) weight overflows VMEM at real widths: tile F
+    # in lane multiples (or take all of it when F has no such divisor)
+    bf = block(F, block_f)
+    if bf % 128:
+        bf = F
     offs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                             jnp.cumsum(group_sizes.astype(jnp.int32))])
 
     kernel = functools.partial(_kernel, bt=bt, E=E)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nt, E),
+        grid=(nt, F // bf, E),
         in_specs=[
-            pl.BlockSpec((bt, D), lambda t, e, offs: (t, 0)),
-            pl.BlockSpec((1, D, F), lambda t, e, offs: (e, 0, 0)),
+            pl.BlockSpec((bt, D), lambda t, f, e, offs: (t, 0)),
+            pl.BlockSpec((1, D, bf), lambda t, f, e, offs: (e, 0, f)),
         ],
-        out_specs=pl.BlockSpec((bt, F), lambda t, e, offs: (t, 0)),
-        scratch_shapes=[pltpu.VMEM((bt, F), jnp.float32)],
+        out_specs=pl.BlockSpec((bt, bf), lambda t, f, e, offs: (t, f)),
+        scratch_shapes=[pltpu.VMEM((bt, bf), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,

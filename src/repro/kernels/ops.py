@@ -5,9 +5,11 @@ Every op picks an implementation:
   - ``ref``      : the pure-jnp oracle in :mod:`repro.kernels.ref`
   - ``auto``     : pallas on TPU backends, ref elsewhere (the default)
 
-The dry-run container is CPU-only, so production lowering exercises the
-ref path; kernels are validated against the oracles in interpret mode by
-``tests/test_kernels.py``.
+On TPU every op, windowed decode included, runs its Pallas kernel.  On
+CPU the composed path runs the oracles; the kernels are checked against
+them in interpret mode (``tests/test_kernels.py``,
+``tests/test_paged_kernels.py``), compiled for a described v5e chip by
+``tests/test_tpu_compile.py``, and run on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -51,14 +53,12 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 
 def decode_attention(q, k_cache, v_cache, length, *, scale=None, window=None):
     use = _use_pallas()
-    if use is None or window is not None:
-        # the Pallas decode kernel has no sliding-window mask yet; windowed
-        # paged decode (LOCAL_ATTN under HyperServe) takes the oracle path
+    if use is None:
         return ref.decode_attention(q, k_cache, v_cache, length, scale=scale,
                                     window=window)
     from repro.kernels import decode_attention as da
     return da.decode_attention(q, k_cache, v_cache, length, scale=scale,
-                               interpret=not use)
+                               window=window, interpret=not use)
 
 
 def resolve_paged_path(kernels: str) -> str:

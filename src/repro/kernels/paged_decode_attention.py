@@ -4,11 +4,13 @@ The composed lowering (``models/attention.attn_decode_paged``) gathers a
 dense ``(B, W*block_size, KV, hd)`` copy of the cache out of the pool and
 then runs flash over it — on a bandwidth-bound decode step that reads the
 cache twice (gather + flash) and burns HBM on the copy.  Here the gather
-disappears: the grid iterates ``(batch, kv_head, page)`` and the K/V
+disappears: the grid iterates ``(batch, page)`` and the K/V
 ``BlockSpec`` index_maps index the *pool's block axis through the
 scalar-prefetched block table* (``pltpu.PrefetchScalarGridSpec``), so each
-page streams HBM→VMEM exactly once, straight from the pool, and the whole
-decode step is ONE kernel.
+page (every KV head of it) streams HBM→VMEM exactly once, straight from
+the pool, and the whole decode step is ONE kernel.  A block of one page
+with all its KV heads keeps the block's last two dims equal to the pool's,
+which is what the TPU tiling accepts; the kernel loops the KV heads.
 
 Two variants share the flash-style running-softmax accumulator:
 
@@ -42,8 +44,8 @@ NEG_INF = -1e30
 # GQA / MHA (ATTN, LOCAL_ATTN)
 # ---------------------------------------------------------------------------
 def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, bs, nw, scale, window):
-    b, w = pl.program_id(0), pl.program_id(2)
+                   acc_ref, m_ref, l_ref, *, bs, nw, kv, scale, window):
+    b, w = pl.program_id(0), pl.program_id(1)
 
     @pl.when(w == 0)
     def _init():
@@ -60,32 +62,36 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                       # (G, D)
-        k = k_ref[0, :, 0, :]                 # (bs, D)
-        v = v_ref[0, :, 0, :]                 # (bs, Dv)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        G = q_ref.shape[2]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
         valid = pos < length
         if window is not None:
             valid &= pos >= length - window
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
-        m_ref[...] = m_new
+        # one page holds every KV head; the G query heads of each KV head
+        # attend its (bs, D) slice
+        for h in range(kv):
+            q = q_ref[0, h]                   # (G, D)
+            k = k_ref[0, :, h, :]             # (bs, D)
+            v = v_ref[0, :, h, :]             # (bs, Dv)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=-1)
+            pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * corr[..., None] + pv
+            m_ref[h] = m_new
 
     @pl.when(w == nw - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)[..., None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)[..., None]
+                    ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -109,27 +115,27 @@ def paged_decode_attention(
     scale = scale if scale is not None else D ** -0.5
     qh = q.reshape(B, KV, G, D)
 
-    kernel = functools.partial(_decode_kernel, bs=block_size, nw=W,
+    kernel = functools.partial(_decode_kernel, bs=block_size, nw=W, kv=KV,
                                scale=scale, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, W),              # page axis innermost: sequential acc
+        grid=(B, W),                  # page axis innermost: sequential acc
         in_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda b, h, w, tab, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, KV, G, D), lambda b, w, tab, lens: (b, 0, 0, 0)),
             # the in-kernel block-table walk: the pool's block axis is
-            # indexed through the prefetched table, one page per grid step
-            pl.BlockSpec((1, block_size, 1, D),
-                         lambda b, h, w, tab, lens: (tab[b, w], 0, h, 0)),
-            pl.BlockSpec((1, block_size, 1, Dv),
-                         lambda b, h, w, tab, lens: (tab[b, w], 0, h, 0)),
+            # indexed through the prefetched table, one whole page (all KV
+            # heads, so the block's last two dims are the pool's) per step
+            pl.BlockSpec((1, block_size, KV, D),
+                         lambda b, w, tab, lens: (tab[b, w], 0, 0, 0)),
+            pl.BlockSpec((1, block_size, KV, Dv),
+                         lambda b, w, tab, lens: (tab[b, w], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dv),
-                               lambda b, h, w, tab, lens: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, Dv),
+                               lambda b, w, tab, lens: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, Dv), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((KV, G, Dv), jnp.float32),
+            pltpu.VMEM((KV, G), jnp.float32),
+            pltpu.VMEM((KV, G), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -7,9 +7,10 @@ row's history, filler rows (scheduler padding, ``limit == 0``) pay full
 price for garbage, and the pool is read twice (gather + flash).
 
 This kernel consumes the scheduler's per-row ``(start, limit)`` vectors
-directly.  The grid iterates ``(row, kv_head, page)`` with the page axis
-walked through the scalar-prefetched block table (one HBM→VMEM stream
-per page, straight from the pool), and ``pl.when`` skips the pages the
+directly.  The grid iterates ``(row, page)`` with the page axis walked
+through the scalar-prefetched block table (one HBM→VMEM stream per page,
+all KV heads at once, straight from the pool; the kernel loops the KV
+heads), and ``pl.when`` skips the pages the
 composed path merely masks:
 
   - dead rows (``limit == 0``) — filler never touches the MXU;
@@ -37,8 +38,9 @@ NEG_INF = -1e30
 
 
 def _prefill_kernel(tab_ref, start_ref, limit_ref, q_ref, k_ref, v_ref,
-                    o_ref, acc_ref, m_ref, l_ref, *, bs, nw, scale, window):
-    p_, w = pl.program_id(0), pl.program_id(2)
+                    o_ref, acc_ref, m_ref, l_ref, *, bs, nw, kv, scale,
+                    window):
+    p_, w = pl.program_id(0), pl.program_id(1)
 
     @pl.when(w == 0)
     def _init():
@@ -48,7 +50,7 @@ def _prefill_kernel(tab_ref, start_ref, limit_ref, q_ref, k_ref, v_ref,
 
     start = start_ref[p_]
     limit = limit_ref[p_]
-    C = q_ref.shape[2]
+    G, C, D = q_ref.shape[2:]
     # page live: row is real work AND some key in the page is visible to
     # some query (causal upper bound; window lower bound)
     live = (limit > 0) & (w * bs <= start + C - 1)
@@ -57,31 +59,36 @@ def _prefill_kernel(tab_ref, start_ref, limit_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0]                       # (C, G, D)
-        k = k_ref[0, :, 0, :]                 # (bs, D)
-        v = v_ref[0, :, 0, :]                 # (bs, Dv)
-        s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        qp = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kp = w * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        qp = start + jax.lax.broadcasted_iota(jnp.int32, (C, bs), 0)
+        kp = w * bs + jax.lax.broadcasted_iota(jnp.int32, (C, bs), 1)
         valid = kp <= qp
         if window is not None:
             valid &= qp - kp < window
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((2,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
-        m_ref[...] = m_new
+        # one page holds every KV head; each head's G query heads are
+        # stacked on the row axis, (G, C, D) -> (G*C, D), for one matmul
+        for h in range(kv):
+            q = q_ref[0, h].reshape(G * C, D)
+            k = k_ref[0, :, h, :]             # (bs, D)
+            v = v_ref[0, :, h, :]             # (bs, Dv)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).reshape(G, C, bs) * scale
+            s = jnp.where(valid[None], s, NEG_INF)
+            m_prev = m_ref[h]                 # (G, C)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=-1)
+            pv = jax.lax.dot_general(
+                p.reshape(G * C, bs).astype(v.dtype), v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).reshape(G, C, -1)
+            acc_ref[h] = acc_ref[h] * corr[..., None] + pv
+            m_ref[h] = m_new
 
     @pl.when(w == nw - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...]
+        o_ref[0] = (acc_ref[...]
                     / jnp.maximum(l_ref[...], 1e-30)[..., None]
                     ).astype(o_ref.dtype)
 
@@ -100,39 +107,44 @@ def ragged_prefill_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Fused ragged chunked-prefill flash.  Returns (P, C, H, Dv)."""
-    P, C, H, D = q.shape
+    P, C0, H, D = q.shape
     KV, Dv = k_pool.shape[2], v_pool.shape[3]
     assert H % KV == 0, (H, KV)
     G = H // KV
     W = block_tables.shape[1]
     scale = scale if scale is not None else D ** -0.5
-    qh = q.reshape(P, C, KV, G, D).transpose(0, 2, 1, 3, 4)  # (P,KV,C,G,D)
+    # chunk rows reshape (G, C, D) -> (G*C, D) in-kernel: pad C to 16s
+    # (packed bf16); padded rows are queries past the chunk, dropped below
+    C = -(-C0 // 16) * 16
+    if C > C0:
+        q = jnp.pad(q, ((0, 0), (0, C - C0), (0, 0), (0, 0)))
+    qh = q.reshape(P, C, KV, G, D).transpose(0, 2, 3, 1, 4)  # (P,KV,G,C,D)
 
-    kernel = functools.partial(_prefill_kernel, bs=block_size, nw=W,
+    kernel = functools.partial(_prefill_kernel, bs=block_size, nw=W, kv=KV,
                                scale=scale, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(P, KV, W),
+        grid=(P, W),
         in_specs=[
-            pl.BlockSpec((1, 1, C, G, D),
-                         lambda p, h, w, tab, st, lm: (p, h, 0, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, D),
-                         lambda p, h, w, tab, st, lm: (tab[p, w], 0, h, 0)),
-            pl.BlockSpec((1, block_size, 1, Dv),
-                         lambda p, h, w, tab, st, lm: (tab[p, w], 0, h, 0)),
+            pl.BlockSpec((1, KV, G, C, D),
+                         lambda p, w, tab, st, lm: (p, 0, 0, 0, 0)),
+            pl.BlockSpec((1, block_size, KV, D),
+                         lambda p, w, tab, st, lm: (tab[p, w], 0, 0, 0)),
+            pl.BlockSpec((1, block_size, KV, Dv),
+                         lambda p, w, tab, st, lm: (tab[p, w], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, C, G, Dv),
-                               lambda p, h, w, tab, st, lm: (p, h, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, C, Dv),
+                               lambda p, w, tab, st, lm: (p, 0, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((C, G, Dv), jnp.float32),
-            pltpu.VMEM((C, G), jnp.float32),
-            pltpu.VMEM((C, G), jnp.float32),
+            pltpu.VMEM((KV, G, C, Dv), jnp.float32),
+            pltpu.VMEM((KV, G, C), jnp.float32),
+            pltpu.VMEM((KV, G, C), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, KV, C, G, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((P, KV, G, C, Dv), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
       limits.astype(jnp.int32), qh, k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3, 4).reshape(P, C, H, Dv)
+    return out.transpose(0, 3, 1, 2, 4).reshape(P, C, H, Dv)[:, :C0]

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.api import PlanError, Supernode, plans
 from repro.configs.base import FabricConfig, ServeConfig, TenantSpec, get_config
+from repro.launch import compile_cache
 from repro.models import model as M
 
 
@@ -102,6 +103,7 @@ def main():
     ap.add_argument("--metrics", action="store_true",
                     help="print the Prometheus metrics dump after the run")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:

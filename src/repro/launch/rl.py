@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.api import PlanError, Supernode, plans
 from repro.configs.base import RLConfig, ServeConfig, get_config
+from repro.launch import compile_cache
 from repro.models import model as M
 
 
@@ -71,6 +72,7 @@ def main():
                     help="capture a HyperTrace timeline and write "
                          "Perfetto/Chrome trace_event JSON here")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:

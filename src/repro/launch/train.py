@@ -17,6 +17,7 @@ import argparse
 
 from repro.api import Supernode, plans
 from repro.configs.base import SHAPES, ShapeConfig, get_config
+from repro.launch import compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import TrainConfig
 
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--mesh", default="none", choices=["none", "auto"])
     ap.add_argument("--ckpt-dir", default="")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -25,7 +25,7 @@ from __future__ import annotations
 import os
 import shutil
 import tempfile
-from typing import Dict, Iterable, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
@@ -191,6 +191,10 @@ class TierStack:
 
     def keys(self) -> Iterable:
         return list(self._host) + list(self._disk)
+
+    def host_values(self) -> List[object]:
+        """Values in the host tier, without touching LRU recency."""
+        return [e.value for e in self._host.values()]
 
     def tier_of(self, key) -> Optional[str]:
         if key in self._host:

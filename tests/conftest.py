@@ -13,20 +13,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 
-# The container has no `hypothesis` wheel (and installs are forbidden);
-# register the mini shim so the property-test files collect and run.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _here = os.path.dirname(os.path.abspath(__file__))
-    if _here not in sys.path:
-        sys.path.insert(0, _here)
-    import _mini_hypothesis as _mh
-
-    _hyp, _st = _mh._as_module()
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 900):
     """Run python code in a subprocess with a forced device count."""

@@ -116,6 +116,25 @@ def test_host_archive_budget_spills_to_disk_and_fetches_back():
     assert ar.counters["evict_host"] == 1
 
 
+def test_host_archive_places_on_host_memory_without_a_mesh():
+    """One device and no mesh: archived arrays sit in the backend's host
+    memory kind, and come back in device memory."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.compat import device_memory_kind, host_memory_kind
+    from repro.core.kvcache import HostArchive
+
+    ar = HostArchive()
+    ar.put(("req", 0), {"pages": jnp.ones((2, 3, 4), jnp.float32)})
+    assert ar.host_memory_kinds() == {host_memory_kind()}
+    got = ar.fetch(("req", 0), pop=True)["pages"]
+    assert got.sharding.memory_kind == device_memory_kind()
+    assert got.sharding.device_set == {jax.devices()[0]}
+    np.testing.assert_array_equal(np.asarray(got), 1.0)
+    assert ar.host_memory_kinds() == set()
+
+
 def test_host_archive_capacity_error_is_typed():
     from repro.core.kvcache import HostArchive
 

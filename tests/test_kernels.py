@@ -167,3 +167,192 @@ def test_flash_chunk_composability():
     c = ref.flash_chunk(q, k[:, 64:], v[:, 64:], c, causal=False, k_offset=64)
     out = ref.flash_finalize(c[0], c[2], q.dtype)
     assert _mx(full, out) < 1e-5
+
+
+@pytest.mark.parametrize("window", [1, 100, 300])
+def test_decode_attention_window(window):
+    """Sliding-window decode in the kernel (keys below length - window
+    masked) against the oracle, lengths straddling block edges."""
+    ks = jax.random.split(KEY, 3)
+    B, S, H, KV, D = 3, 512, 4, 2, 64
+    q = jax.random.normal(ks[0], (B, 1, H, D)) * 0.3
+    kc = jax.random.normal(ks[1], (B, S, KV, D)) * 0.3
+    vc = jax.random.normal(ks[2], (B, S, KV, D)) * 0.3
+    lens = jnp.asarray([512, 130, 257], jnp.int32)
+    out = decode_attention(q, kc, vc, lens, window=window, interpret=True,
+                           block_s=128)
+    exp = ref.decode_attention(q, kc, vc, lens, window=window)
+    assert _mx(out, exp) < 2e-5
+
+
+def test_flash_attention_traced_row_offsets():
+    """Per-row traced q_offset under vmap + jit (the composed prefill's
+    flash_rows) matches the oracle row by row."""
+    ks = jax.random.split(KEY, 3)
+    P, C, S, H, KV, D = 3, 64, 256, 4, 2, 32
+    q = jax.random.normal(ks[0], (P, C, H, D)) * 0.3
+    k = jax.random.normal(ks[1], (P, S, KV, D)) * 0.3
+    v = jax.random.normal(ks[2], (P, S, KV, D)) * 0.3
+    starts = jnp.asarray([0, 64, 192], jnp.int32)
+
+    @jax.jit
+    def rows(q, k, v, starts):
+        return jax.vmap(lambda a, b, c, o: flash_attention(
+            a[None], b[None], c[None], q_offset=o, interpret=True,
+            block_q=64, block_k=64)[0])(q, k, v, starts)
+
+    out = rows(q, k, v, starts)
+    for r in range(P):
+        exp = ref.flash_attention(q[r:r + 1], k[r:r + 1], v[r:r + 1],
+                                  q_offset=int(starts[r]))
+        assert _mx(out[r:r + 1], exp) < 2e-5
+
+
+@pytest.mark.parametrize("window", [None, 96])
+def test_flash_attention_gradient(window):
+    """custom_vjp: grads through the kernel equal the oracle's grads."""
+    ks = jax.random.split(KEY, 4)
+    B, S, H, KV, D = 1, 256, 4, 2, 32
+    q = jax.random.normal(ks[0], (B, S, H, D)) * 0.3
+    k = jax.random.normal(ks[1], (B, S, KV, D)) * 0.3
+    v = jax.random.normal(ks[2], (B, S, KV, D)) * 0.3
+    ct = jax.random.normal(ks[3], (B, S, H, D))
+
+    def loss(f):
+        return lambda q_, k_, v_: jnp.sum(f(q_, k_, v_) * ct)
+
+    got = jax.grad(loss(lambda *a: flash_attention(
+        *a, window=window, interpret=True, block_q=128, block_k=128)),
+        (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: ref.flash_attention(*a, window=window)),
+                    (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert _mx(g, w) < 1e-5
+
+
+def test_ssd_scan_init_state():
+    """Chunked prefill resumes from a carried state: scanning the second
+    half from the first half's final state equals one whole scan."""
+    ks = jax.random.split(KEY, 5)
+    B, S, H, P, N = 2, 128, 2, 16, 8
+    x = jax.random.normal(ks[0], (B, S, H, P), jnp.float32) * 0.3
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
+    Bm = jax.random.normal(ks[3], (B, S, N)) * 0.3
+    Cm = jax.random.normal(ks[4], (B, S, N)) * 0.3
+    h = S // 2
+    y_all, f_all = ref.ssd_scan(x, dt, A, Bm, Cm, chunk=32)
+    _, f1 = ssd_scan(x[:, :h], dt[:, :h], A, Bm[:, :h], Cm[:, :h], chunk=32,
+                     interpret=True)
+    y2, f2 = ssd_scan(x[:, h:], dt[:, h:], A, Bm[:, h:], Cm[:, h:], chunk=32,
+                      init_state=f1, interpret=True)
+    assert _mx(y2, y_all[:, h:]) < 1e-3 and _mx(f2, f_all) < 1e-3
+
+
+def test_rglru_scan_init_state():
+    ks = jax.random.split(KEY, 3)
+    B, S, W = 2, 128, 256
+    x = jax.random.normal(ks[0], (B, S, W), jnp.float32) * 0.5
+    ig = jax.nn.sigmoid(jax.random.normal(ks[1], (B, S, W)))
+    ag = jax.nn.sigmoid(jax.random.normal(ks[2], (B, S, W)))
+    la = -jax.nn.softplus(-jnp.linspace(2, 6, W))
+    h_all, f_all = ref.rglru_scan(x, ig, ag, la)
+    h = S // 2
+    _, f1 = rglru_scan(x[:, :h], ig[:, :h], ag[:, :h], la, interpret=True,
+                       block_s=32, block_w=128)
+    h2, f2 = rglru_scan(x[:, h:], ig[:, h:], ag[:, h:], la, init_state=f1,
+                        interpret=True, block_s=32, block_w=128)
+    assert _mx(h2, h_all[:, h:]) < 1e-4 and _mx(f2, f_all) < 1e-4
+
+
+def test_grouped_matmul_f_tiles():
+    """F split into several lane tiles (block_f < F)."""
+    ks = jax.random.split(KEY, 2)
+    T, D, F, E = 256, 64, 512, 4
+    x = jax.random.normal(ks[0], (T, D)) * 0.3
+    w = jax.random.normal(ks[1], (E, D, F)) * 0.3
+    sizes = jnp.asarray([100, 0, 120, 36], jnp.int32)
+    out = grouped_matmul(x, w, sizes, interpret=True, block_t=64,
+                         block_f=128)
+    assert _mx(out, ref.grouped_matmul(x, w, sizes)) < 2e-5
+
+
+@pytest.mark.parametrize("Sq,Sk,causal,q_offset,window", [
+    (45, 45, True, 0, None),           # no 16-row divisor: padded
+    (300, 300, True, 0, 64),
+    (40, 300, False, 0, None),         # padded keys must be masked
+    (24, 300, True, 276, None),        # chunk at the end of a long prefix
+])
+def test_flash_attention_padded_lengths(Sq, Sk, causal, q_offset, window):
+    ks = jax.random.split(KEY, 3)
+    H, KV, D = 4, 2, 32
+    q = jax.random.normal(ks[0], (1, Sq, H, D)) * 0.3
+    k = jax.random.normal(ks[1], (1, Sk, KV, D)) * 0.3
+    v = jax.random.normal(ks[2], (1, Sk, KV, D)) * 0.3
+    out = flash_attention(q, k, v, causal=causal, window=window,
+                          q_offset=q_offset, interpret=True, block_q=128,
+                          block_k=128)
+    exp = ref.flash_attention(q, k, v, causal=causal, window=window,
+                              q_offset=q_offset)
+    assert out.shape == exp.shape
+    assert _mx(out, exp) < 2e-5
+
+
+def test_decode_attention_padded_cache():
+    """A cache length with no 8-row divisor below the block is padded."""
+    ks = jax.random.split(KEY, 3)
+    B, S, H, KV, D = 2, 513, 4, 2, 32
+    q = jax.random.normal(ks[0], (B, 1, H, D)) * 0.3
+    kc = jax.random.normal(ks[1], (B, S, KV, D)) * 0.3
+    vc = jax.random.normal(ks[2], (B, S, KV, D)) * 0.3
+    lens = jnp.asarray([513, 200], jnp.int32)
+    out = decode_attention(q, kc, vc, lens, interpret=True, block_s=128)
+    assert _mx(out, ref.decode_attention(q, kc, vc, lens)) < 2e-5
+
+
+def test_ssd_scan_gradient():
+    """Grads through the kernel (oracle VJP) equal the oracle's, init
+    state included."""
+    ks = jax.random.split(KEY, 7)
+    B, S, H, P, N = 1, 64, 2, 16, 8
+    x = jax.random.normal(ks[0], (B, S, H, P)) * 0.3
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
+    Bm = jax.random.normal(ks[3], (B, S, N)) * 0.3
+    Cm = jax.random.normal(ks[4], (B, S, N)) * 0.3
+    s0 = jax.random.normal(ks[5], (B, H, P, N)) * 0.3
+    ct = jax.random.normal(ks[6], (B, S, H, P))
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a[:5], init_state=a[5])[0] * ct)
+
+    args = (x, dt, A, Bm, Cm, s0)
+    got = jax.grad(loss(lambda *a, **k: ssd_scan(*a, chunk=16, interpret=True,
+                                                 **k)),
+                   range(6))(*args)
+    want = jax.grad(loss(lambda *a, **k: ref.ssd_scan(*a, chunk=16, **k)),
+                    range(6))(*args)
+    for g, w in zip(got, want):
+        assert _mx(g, w) < 1e-5
+
+
+def test_rglru_scan_gradient():
+    ks = jax.random.split(KEY, 5)
+    B, S, W = 1, 64, 128
+    x = jax.random.normal(ks[0], (B, S, W)) * 0.5
+    ig = jax.nn.sigmoid(jax.random.normal(ks[1], (B, S, W)))
+    ag = jax.nn.sigmoid(jax.random.normal(ks[2], (B, S, W)))
+    la = -jax.nn.softplus(-jnp.linspace(2, 6, W))
+    h0 = jax.random.normal(ks[3], (B, W)) * 0.3
+    ct = jax.random.normal(ks[4], (B, S, W))
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a[:4], init_state=a[4])[0] * ct)
+
+    args = (x, ig, ag, la, h0)
+    got = jax.grad(loss(lambda *a, **k: rglru_scan(*a, interpret=True,
+                                                   block_s=32, **k)),
+                   range(5))(*args)
+    want = jax.grad(loss(ref.rglru_scan), range(5))(*args)
+    for g, w in zip(got, want):
+        assert _mx(g, w) < 1e-5

@@ -20,13 +20,12 @@ def test_collective_matmul_matches_plain():
     run_subprocess("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
 from repro.core.overlap import collective_matmul_allgather
 mesh = jax.make_mesh((4,), ("model",),
                      axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (64, 32)) * 0.3
 w = jax.random.normal(jax.random.PRNGKey(1), (32, 16)) * 0.3
-fn = shard_map(lambda xl, wl: collective_matmul_allgather(xl, wl, axis_name="model"),
+fn = jax.shard_map(lambda xl, wl: collective_matmul_allgather(xl, wl, axis_name="model"),
                mesh=mesh, in_specs=(P("model", None), P(None, None)),
                out_specs=P(None, None), check_vma=False)
 got = fn(x, w)

@@ -84,6 +84,23 @@ def test_ragged_prefill_parity(window):
     assert jnp.all(got[3] == 0.0)
 
 
+def test_ragged_prefill_padded_chunk():
+    """A chunk width with no 16-row alignment (C=6) is padded in the
+    wrapper; outputs keep the caller's width."""
+    H, KV, D, C = 4, 2, 16, 6
+    starts = jnp.asarray([0, 6, 18], jnp.int32)
+    limits = jnp.asarray([5, 12, 24], jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(6), (3, C, H, D)) * 0.3
+    k_pool, v_pool = _pools(jax.random.PRNGKey(7), KV, D)
+    tables = _tables(3)
+    got = ragged_prefill_attention(q, k_pool, v_pool, tables, starts, limits,
+                                   block_size=BS, interpret=True)
+    want = ref.ragged_prefill_attention(q, k_pool, v_pool, tables, starts,
+                                        limits, block_size=BS)
+    assert got.shape == want.shape == (3, C, H, D)
+    assert jnp.max(jnp.abs(got - want)) < 2e-5
+
+
 def test_mla_decode_parity():
     """Absorbed MLA decode in latent space over the compressed pools."""
     B, H, R, r = 3, 4, 16, 8

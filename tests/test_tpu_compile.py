@@ -1,0 +1,180 @@
+"""Every Pallas kernel compiles for a TPU v5e chip at real model widths.
+
+The TPU compiler ships with libtpu, which compiles for a described chip
+without one attached: each test lowers a kernel (or a whole gradient)
+with shapes placed on one device of a described ``v5e:2x2`` topology and
+asserts the compiled HLO holds the kernel (``tpu_custom_call``).  That
+catches what interpret mode cannot: block shapes the TPU tiling refuses,
+primitives Mosaic does not lower, kernels that overflow VMEM.  Nothing
+runs, so numbers are checked elsewhere (``tests/test_kernels.py``,
+``tests/test_paged_kernels.py`` and ``chip_smoke.py`` on the chip).
+
+The topology is described inside a fixture, never at import: only one
+process may hold libtpu, and every test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import get_config
+from repro.kernels import (decode_attention as da, flash_attention as fa,
+                           grouped_matmul as gm, paged_decode_attention as pda,
+                           ragged_prefill_attention as rpa, rglru_scan as rs,
+                           ssd_scan as ss)
+
+QWEN = get_config("qwen2-0.5b")
+H, KV, D = QWEN.num_heads, QWEN.num_kv_heads, QWEN.resolved_head_dim
+BS, N, W, B = 16, 512, 34, 8          # page size, pool pages, table, seats
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - no libtpu / no TPU compiler
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent
+        # cache but cannot be read back without one: keep the cache off
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    return hlo
+
+
+BF, I32 = jnp.bfloat16, jnp.int32
+
+
+@pytest.mark.parametrize("window", [None, 64])
+def test_paged_decode_compiles(one_chip, window):
+    _compile(one_chip,
+             lambda q, k, v, t, n: pda.paged_decode_attention(
+                 q, k, v, t, n, block_size=BS, window=window),
+             ((B, 1, H, D), BF), ((N, BS, KV, D), BF), ((N, BS, KV, D), BF),
+             ((B, W), I32), ((B,), I32))
+
+
+@pytest.mark.parametrize("rows,chunk", [(4, 128), (1, 32), (2, 24)])
+def test_ragged_prefill_compiles(one_chip, rows, chunk):
+    _compile(one_chip,
+             lambda q, k, v, t, s, n: rpa.ragged_prefill_attention(
+                 q, k, v, t, s, n, block_size=BS),
+             ((rows, chunk, H, D), BF), ((N, BS, KV, D), BF),
+             ((N, BS, KV, D), BF), ((rows, W), I32), ((rows,), I32),
+             ((rows,), I32))
+
+
+@pytest.mark.parametrize("cache,window", [(544, None), (544, 128),
+                                          (513, None)])
+def test_dense_decode_compiles(one_chip, cache, window):
+    _compile(one_chip,
+             lambda q, k, v, n: da.decode_attention(q, k, v, n,
+                                                    window=window),
+             ((B, 1, H, D), BF), ((B, cache, KV, D), BF),
+             ((B, cache, KV, D), BF), ((B,), I32))
+
+
+@pytest.mark.parametrize("seq", [1024, 300, 45])
+def test_flash_forward_compiles(one_chip, seq):
+    _compile(one_chip, lambda q, k, v: fa.flash_attention(q, k, v),
+             ((4, seq, H, D), BF), ((4, seq, KV, D), BF),
+             ((4, seq, KV, D), BF))
+
+
+def test_flash_traced_row_offsets_compile(one_chip):
+    """The composed prefill's per-row ``q_offset`` is a traced vector
+    under vmap (``models.attention.flash_rows``)."""
+    def rows(q, k, v, starts):
+        return jax.vmap(lambda a, b, c, o: fa.flash_attention(
+            a[None], b[None], c[None], q_offset=o)[0])(q, k, v, starts)
+    _compile(one_chip, rows, ((4, 128, H, D), BF), ((4, 544, KV, D), BF),
+             ((4, 544, KV, D), BF), ((4,), I32))
+
+
+def test_flash_gradient_compiles(one_chip):
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+    _compile(one_chip, jax.value_and_grad(loss, argnums=(0, 1, 2)),
+             ((4, 1024, H, D), BF), ((4, 1024, KV, D), BF),
+             ((4, 1024, KV, D), BF))
+
+
+def test_mla_paged_decode_compiles(one_chip):
+    cfg = get_config("deepseek-v2-lite-16b")
+    R, r = cfg.mla.kv_lora_rank, cfg.mla.qk_rope_head_dim
+    _compile(one_chip,
+             lambda ql, qr, c, kr, t, n: pda.paged_mla_decode_attention(
+                 ql, qr, c, kr, t, n, block_size=BS, scale=0.07),
+             ((B, cfg.num_heads, R), BF), ((B, cfg.num_heads, r), BF),
+             ((N, BS, R), BF), ((N, BS, r), BF), ((B, W), I32), ((B,), I32))
+
+
+@pytest.mark.parametrize("with_state", [False, True])
+def test_ssd_scan_compiles(one_chip, with_state):
+    cfg = get_config("mamba2-370m")
+    s = cfg.ssm
+    nh, P, Nst, S = s.num_heads(cfg.d_model), s.head_dim, s.d_state, 2048
+    shapes = [((1, S, nh, P), BF), ((1, S, nh), jnp.float32),
+              ((nh,), jnp.float32), ((1, S, Nst), BF), ((1, S, Nst), BF)]
+    if with_state:
+        shapes.append(((1, nh, P, Nst), jnp.float32))
+    _compile(one_chip,
+             lambda x, dt, A, Bm, Cm, *s0: ss.ssd_scan(
+                 x, dt, A, Bm, Cm, chunk=s.chunk_size,
+                 init_state=s0[0] if s0 else None),
+             *shapes)
+
+
+def test_scan_gradients_compile(one_chip):
+    """Training SSD and RG-LRU layers differentiates through the kernels."""
+    cfg = get_config("mamba2-370m")
+    s = cfg.ssm
+    nh, P, Nst, S = s.num_heads(cfg.d_model), s.head_dim, s.d_state, 1024
+    Wd = get_config("recurrentgemma-2b").rglru.lru_width
+
+    def loss(x, dt, A, Bm, Cm, xr, ig, ag, la):
+        y, _ = ss.ssd_scan(x, dt, A, Bm, Cm, chunk=s.chunk_size)
+        h, _ = rs.rglru_scan(xr, ig, ag, la)
+        return y.astype(jnp.float32).sum() + h.astype(jnp.float32).sum()
+    _compile(one_chip, jax.value_and_grad(loss, argnums=tuple(range(9))),
+             ((1, S, nh, P), BF), ((1, S, nh), jnp.float32),
+             ((nh,), jnp.float32), ((1, S, Nst), BF), ((1, S, Nst), BF),
+             ((1, S, Wd), BF), ((1, S, Wd), BF), ((1, S, Wd), BF),
+             ((Wd,), jnp.float32))
+
+
+@pytest.mark.parametrize("with_state", [False, True])
+def test_rglru_scan_compiles(one_chip, with_state):
+    Wd = get_config("recurrentgemma-2b").rglru.lru_width
+    shapes = [((1, 2048, Wd), BF)] * 3 + [((Wd,), jnp.float32)]
+    if with_state:
+        shapes.append(((1, Wd), BF))
+    _compile(one_chip,
+             lambda x, i, a, la, *h0: rs.rglru_scan(
+                 x, i, a, la, init_state=h0[0] if h0 else None),
+             *shapes)
+
+
+def test_grouped_matmul_compiles(one_chip):
+    """An 8-expert share of deepseek-moe-16b's experts."""
+    cfg = get_config("deepseek-moe-16b")
+    Dm, F = cfg.d_model, cfg.moe.d_ff_expert
+    _compile(one_chip, gm.grouped_matmul, ((2048, Dm), BF),
+             ((8, Dm, F), BF), ((8,), I32))
