@@ -111,6 +111,7 @@ def decode_attention(q, k_cache, v_cache, length, *, scale=None,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, Dv), q.dtype),
+        name="decode_attention",
         interpret=interpret,
     )(lens, qh, kh, vh)
     return out.reshape(B, KV, G, Dv).reshape(B, 1, H, Dv)

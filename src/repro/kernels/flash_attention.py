@@ -132,6 +132,7 @@ def _forward(q, k, v, q_offset, *, causal, window, scale, block_q, block_k,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, Sq, Dv), q.dtype),
+        name="flash_attention",
         interpret=interpret,
     )(jnp.reshape(q_offset, (1,)), qh, kh, vh)
     return (out.reshape(B, KV, G, Sq, Dv).transpose(0, 3, 1, 2, 4)

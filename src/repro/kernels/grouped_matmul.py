@@ -80,6 +80,7 @@ def grouped_matmul(x, w, group_sizes, *, block_t: int = 256,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, F), x.dtype),
+        name="grouped_matmul",
         interpret=interpret,
     )(offs, x, w)
     return out

@@ -141,6 +141,7 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Dv), q.dtype),
+        name="paged_decode_attention",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       qh, k_pool, v_pool)
@@ -232,6 +233,7 @@ def paged_mla_decode_attention(
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
+        name="paged_mla_decode_attention",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q_lat, q_rope, ckv_pool, krope_pool)

@@ -144,6 +144,7 @@ def ragged_prefill_attention(
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, KV, G, C, Dv), q.dtype),
+        name="ragged_prefill_attention",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), starts.astype(jnp.int32),
       limits.astype(jnp.int32), qh, k_pool, v_pool)

@@ -113,6 +113,7 @@ def _scan(x, input_gate, a_gate, log_a, init_state, *, c, block_s, block_w,
             jax.ShapeDtypeStruct((B, 1, W), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
+        name="rglru_scan",
         interpret=interpret,
     )(x, input_gate, a_gate, la, h0)
     return h, fin.reshape(B, W)

@@ -128,6 +128,7 @@ def _scan(x, dt, A, Bm, Cm, init_state, *, Q, interpret):
             jax.ShapeDtypeStruct((B * H, S, P), x.dtype),
             jax.ShapeDtypeStruct((B * H, P, N), x.dtype),
         ],
+        name="ssd_scan",
         interpret=interpret,
     )(A.astype(jnp.float32), xh, dth, Bm, Cm, s0)
     y = y.reshape(B, H, S, P).transpose(0, 2, 1, 3)

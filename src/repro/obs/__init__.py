@@ -12,7 +12,9 @@ Scoping: each :class:`~repro.api.session.Supernode` owns one hub (all
 engines it builds share it — ``session.obs()``), and engines constructed
 directly default to a private hub so per-engine counters stay clean.
 ``default_obs()`` is the process-global fallback for scripts and
-launchers.  Zero third-party dependencies; nothing here imports jax.
+launchers.  Zero third-party dependencies; nothing here imports jax at
+load (``Tracer.enable()`` imports ``jax.profiler``'s annotations, the
+tracer's second sink, when jax is installed).
 
     obs = session.obs()
     obs.trace.enable()

@@ -13,6 +13,16 @@ returns one shared no-op context manager (no allocation, one attribute
 read + branch), so instrumentation can live permanently on the hot paths
 — the engine loop pays for tracing only while a trace is being captured.
 
+Two sinks, one span: while enabled, each ``span()`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name (``step_span()`` a
+``StepTraceAnnotation``), so under a running profiler session the span
+lands in the xplane host plane on the device trace's clock, and device
+idle time can be put down to what the host was doing.  The annotation
+factories are imported on the first ``enable()``, never at module load
+(this module imports no jax), or injected (``Tracer(annotations=...)``).
+``complete()`` records a span after the fact and so reaches the JSON
+ring only: the profiler cannot be told about an interval that is over.
+
 Timestamps are ``time.perf_counter_ns`` relative to the tracer's epoch,
 exported in microseconds (the trace_event unit).  Named **tracks**
 (``track="actor"``) map to synthetic tids with thread_name metadata so
@@ -25,7 +35,7 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 
 class _NoopSpan:
@@ -38,34 +48,61 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 NOOP_SPAN = _NoopSpan()
 
 
-class _Span:
-    __slots__ = ("_tracer", "name", "track", "args", "t0")
+def _profiler_annotations() -> Optional[Tuple[Callable, Callable]]:
+    """``(TraceAnnotation, StepTraceAnnotation)`` of ``jax.profiler``, or
+    None where jax is not installed (the JSON ring still works)."""
+    try:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation, StepTraceAnnotation
 
-    def __init__(self, tracer: "Tracer", name: str, track, args):
+
+class _Span:
+    __slots__ = ("_tracer", "name", "track", "args", "t0", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, track, args, ann):
         self._tracer = tracer
         self.name = name
         self.track = track
         self.args = args
+        self._ann = ann                      # profiler annotation, or None
+
+    def set(self, **args) -> None:
+        """Add args known only once the span is open (before it closes)."""
+        self.args.update(args)
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.complete(self.name, self.t0, time.perf_counter_ns(),
-                              track=self.track, **(self.args or {}))
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer.complete(self.name, self.t0, t1, track=self.track,
+                              **self.args)
         return False
 
 
 class Tracer:
     """Thread-safe ring-buffer event tracer with Perfetto export."""
 
-    def __init__(self, capacity: int = 65536, pid: int = 1):
+    def __init__(self, capacity: int = 65536, pid: int = 1, *,
+                 annotations: Optional[Tuple[Callable, Callable]] = None):
         self.pid = pid
+        # (span factory, step-span factory) of the profiler sink; None
+        # until the first enable() imports jax.profiler's
+        self._annotations = annotations
         self.capacity = int(capacity)
         self._lock = threading.Lock()
         self._enabled = False
@@ -81,6 +118,8 @@ class Tracer:
         return self._enabled
 
     def enable(self, capacity: Optional[int] = None) -> "Tracer":
+        if self._annotations is None:
+            self._annotations = _profiler_annotations()
         with self._lock:
             if capacity is not None:
                 self.capacity = int(capacity)
@@ -125,14 +164,31 @@ class Tracer:
             self.emitted += 1
 
     def span(self, name: str, *, track: Optional[str] = None, **args):
-        """Context manager timing a region; no-op while disabled."""
+        """Context manager timing a region, in the ring and as a profiler
+        annotation of the same name; no-op while disabled."""
         if not self._enabled:
             return NOOP_SPAN
-        return _Span(self, name, track, args)
+        ann = self._annotations
+        return _Span(self, name, track, args,
+                     ann[0](name) if ann is not None else None)
+
+    def step_span(self, name: str, step: int, *,
+                  track: Optional[str] = None, **args):
+        """``span()`` for one iteration of a loop: ``step`` joins its args,
+        and the profiler gets a ``StepTraceAnnotation`` numbered ``step``."""
+        if not self._enabled:
+            return NOOP_SPAN
+        ann = self._annotations
+        return _Span(self, name, track, dict(args, step=step),
+                     ann[1](name, step_num=step) if ann is not None else None)
 
     def complete(self, name: str, t0_ns: int, t1_ns: int, *,
                  track: Optional[str] = None, **args) -> None:
-        """A finished span with explicit timestamps (async dispatch windows)."""
+        """A finished span with explicit timestamps (async dispatch windows).
+
+        It reaches the JSON ring only: the profiler sink records live
+        regions, so spans timed after the fact (MPMD role dispatch,
+        pipeline stages) are absent from a profiler trace."""
         if not self._enabled:
             return
         ev = {"name": name, "ph": "X", "pid": self.pid,

@@ -90,7 +90,7 @@ def make_rl_step(cfg, mesh: Optional[Mesh], plan: hypershard.ShardingPlan,
     float32, advantages (B,) float32.
     """
 
-    def step(params, opt_state, batch):
+    def grpo_step(params, opt_state, batch):
         ctx = use_mesh(mesh) if mesh is not None else _null()
         with ctx:
             lf = functools.partial(grpo_loss, cfg=cfg, rl_cfg=rl_cfg,
@@ -103,7 +103,8 @@ def make_rl_step(cfg, mesh: Optional[Mesh], plan: hypershard.ShardingPlan,
         return new_params, new_opt, metrics
 
     if mesh is None:
-        return jax.jit(step, donate_argnums=(0, 1) if donate else ()), {}
+        return jax.jit(grpo_step,
+                       donate_argnums=(0, 1) if donate else ()), {}
 
     pshapes = jax.eval_shape(lambda: M.init_model(cfg, jax.random.PRNGKey(0)))
     param_sh = hypershard.make_param_shardings(mesh, pshapes, plan)
@@ -117,7 +118,7 @@ def make_rl_step(cfg, mesh: Optional[Mesh], plan: hypershard.ShardingPlan,
                                     "behaviour_logp")}
     batch_sh["advantages"] = NamedSharding(mesh, P(bspec[0]))
     shardings = {"params": param_sh, "opt_in": opt_in, "batch": batch_sh}
-    step_jit = jax.jit(step,
+    step_jit = jax.jit(grpo_step,
                        in_shardings=(param_sh, opt_in, batch_sh),
                        out_shardings=(param_sh, opt_in, None),
                        donate_argnums=(0, 1) if donate else ())

@@ -145,6 +145,8 @@ class HyperServe:
                        else req.t_first_token - req.t_enqueue),
             "latency_s": (None if req.t_finish is None
                           else req.t_finish - req.t_enqueue),
+            # engine steps from admission to the first token, both counted
+            "prefill_steps": req.prefill_steps,
         }
 
     def join(self, max_steps: int = 100_000) -> Dict[int, List[int]]:

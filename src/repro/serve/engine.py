@@ -210,7 +210,9 @@ def make_paged_serve_step(cfg, mesh: Optional[Mesh], plan, *,
     by the arrays the caller passes (one compilation per distinct shape).
     """
 
-    def step(params, tokens, positions, pools, tables, slot_mask):
+    # the function's name names the XLA module: jit_paged_decode_step
+    def paged_decode_step(params, tokens, positions, pools, tables,
+                          slot_mask):
         ctx = use_mesh(mesh) if mesh is not None else _null()
         with ctx:
             return M.decode_step_paged(params, tokens, positions, cfg, pools,
@@ -221,7 +223,7 @@ def make_paged_serve_step(cfg, mesh: Optional[Mesh], plan, *,
 
     donate_kw = {"donate_argnums": (3,)} if donate else {}
     if mesh is None:
-        return jax.jit(step, **donate_kw), {}
+        return jax.jit(paged_decode_step, **donate_kw), {}
     pshapes = jax.eval_shape(lambda: M.init_model(cfg, jax.random.PRNGKey(0)))
     param_sh = hypershard.make_param_shardings(mesh, pshapes, plan)
     pool_sh = make_pool_shardings(mesh, pool_tree, plan)
@@ -229,7 +231,7 @@ def make_paged_serve_step(cfg, mesh: Optional[Mesh], plan, *,
     tok_sh = NamedSharding(mesh, P(None, None))
     tab_sh = NamedSharding(mesh, P(None, None))
     logits_sh = NamedSharding(mesh, P(None, None, _vocab_axis(cfg, mesh)))
-    jitted = jax.jit(step,
+    jitted = jax.jit(paged_decode_step,
                      in_shardings=(param_sh, tok_sh, rep, pool_sh, tab_sh,
                                    rep),
                      out_shardings=(logits_sh, pool_sh), **donate_kw)
@@ -255,7 +257,9 @@ def make_paged_prefill_step(cfg, mesh: Optional[Mesh], plan, *,
     (one compilation per distinct shape).
     """
 
-    def step(params, tokens, starts, limits, slots, pools, tables):
+    # the function's name names the XLA module: jit_paged_prefill_step
+    def paged_prefill_step(params, tokens, starts, limits, slots, pools,
+                           tables):
         ctx = use_mesh(mesh) if mesh is not None else _null()
         with ctx:
             return M.prefill_chunk_paged(params, tokens, starts, limits,
@@ -266,7 +270,7 @@ def make_paged_prefill_step(cfg, mesh: Optional[Mesh], plan, *,
 
     donate_kw = {"donate_argnums": (5,)} if donate else {}
     if mesh is None:
-        return jax.jit(step, **donate_kw), {}
+        return jax.jit(paged_prefill_step, **donate_kw), {}
     pshapes = jax.eval_shape(lambda: M.init_model(cfg, jax.random.PRNGKey(0)))
     param_sh = hypershard.make_param_shardings(mesh, pshapes, plan)
     pool_sh = make_pool_shardings(mesh, pool_tree, plan)
@@ -274,7 +278,7 @@ def make_paged_prefill_step(cfg, mesh: Optional[Mesh], plan, *,
     tok_sh = NamedSharding(mesh, P(None, None))
     tab_sh = NamedSharding(mesh, P(None, None))
     out0_sh = NamedSharding(mesh, P(None, _vocab_axis(cfg, mesh)))
-    jitted = jax.jit(step,
+    jitted = jax.jit(paged_prefill_step,
                      in_shardings=(param_sh, tok_sh, rep, rep, rep, pool_sh,
                                    tab_sh),
                      out_shardings=(out0_sh, pool_sh), **donate_kw)

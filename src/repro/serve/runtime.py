@@ -386,7 +386,8 @@ class ServeEngine:
     # ------------------------------------------------------------------
     # prefill execution
     # ------------------------------------------------------------------
-    def _run_prefill_batch(self, reqs: List[Request]) -> None:
+    def _run_prefill_batch(self, reqs: List[Request], step: int
+                           ) -> None:
         """Every scheduled prompt chunk in ONE jit call (<= prefill_batch
         rows, filler rows padded to limit 0 / the null slot / the null
         block): a single dispatch and a single kernel launch amortised
@@ -399,35 +400,38 @@ class ServeEngine:
         (prefill_batch, chunk) one — padding waste only ever doubles the
         live rows, while compilations stay O(log prefill_batch).
         """
+        tr = self.obs.trace
         C = self.scfg.prefill_chunk
         Pb = 1
         while Pb < len(reqs):
             Pb *= 2
         Pb = min(Pb, self.scfg.prefill_batch)
         W = self.pcfg.max_blocks_per_req
-        toks = np.zeros((Pb, C), np.int32)
-        starts = np.zeros((Pb,), np.int32)
-        limits = np.zeros((Pb,), np.int32)
-        # filler rows sit in the out-of-range null seat: their slot-state
-        # writes are dropped on device (see models.mamba2.scatter_slot_rows)
-        slots = np.full((Pb,), self.scfg.max_slots, np.int32)
-        tables = np.zeros((Pb, W), np.int32)
-        meta = []
-        for i, req in enumerate(reqs):
-            c0 = req.prefill_done
-            n = min(C, req.prompt_len - c0)
-            toks[i, :n] = req.prompt[c0:c0 + n]
-            starts[i] = c0
-            limits[i] = req.prompt_len
-            slots[i] = req.slot
-            tables[i, :len(req.table)] = req.table
-            meta.append((i, req, n))
-        self.obs.record_compile("paged_prefill", (Pb, C, W))
-        self.obs.metrics.counter(
-            f"serve.kernels.prefill.{self.kernel_path}").inc()
-        with self.obs.trace.span("serve.prefill", track="engine",
-                                 rows=len(reqs), bucket=Pb,
-                                 rids=[r.rid for r in reqs]):
+        with tr.span("serve.prefill.pack", track="engine", step=step):
+            toks = np.zeros((Pb, C), np.int32)
+            starts = np.zeros((Pb,), np.int32)
+            limits = np.zeros((Pb,), np.int32)
+            # filler rows sit in the out-of-range null seat: their
+            # slot-state writes are dropped on device (see
+            # models.mamba2.scatter_slot_rows)
+            slots = np.full((Pb,), self.scfg.max_slots, np.int32)
+            tables = np.zeros((Pb, W), np.int32)
+            meta = []
+            for i, req in enumerate(reqs):
+                c0 = req.prefill_done
+                n = min(C, req.prompt_len - c0)
+                toks[i, :n] = req.prompt[c0:c0 + n]
+                starts[i] = c0
+                limits[i] = req.prompt_len
+                slots[i] = req.slot
+                tables[i, :len(req.table)] = req.table
+                meta.append((i, req, n))
+            self.obs.record_compile("paged_prefill", (Pb, C, W))
+            self.obs.metrics.counter(
+                f"serve.kernels.prefill.{self.kernel_path}").inc()
+        # the prefill dispatch: it ends once the call is enqueued
+        with tr.span("serve.prefill", track="engine", rows=len(reqs),
+                     bucket=Pb, rids=[r.rid for r in reqs], step=step):
             logits, self.pool.state = self._prefill_step(
                 self.params, jnp.asarray(toks), jnp.asarray(starts),
                 jnp.asarray(limits), jnp.asarray(slots), self.pool.state,
@@ -436,14 +440,16 @@ class ServeEngine:
         self.prefill_chunks += len(reqs)
         self.obs.metrics.counter("serve.prefill_calls").inc()
         self.obs.metrics.counter("serve.prefill_chunks").inc(len(reqs))
-        for i, req, n in meta:
-            self.scheduler.on_prefill_chunk(req, n)
-            if req.prefill_done == req.prompt_len:
-                # the step returns each row's LAST in-chunk prompt-token
-                # logits: exactly what seeds the first sampled token
-                first = self._sample(logits[i], req)
-                self.scheduler.on_prompt_complete(req, first)
-                self.tokens_generated += 1
+        # the first sample waits for the prefill program to finish
+        with tr.span("serve.prefill.first_tokens", track="engine", step=step):
+            for i, req, n in meta:
+                self.scheduler.on_prefill_chunk(req, n)
+                if req.prefill_done == req.prompt_len:
+                    # the step returns each row's LAST in-chunk prompt-token
+                    # logits: exactly what seeds the first sampled token
+                    first = self._sample(logits[i], req)
+                    self.scheduler.on_prompt_complete(req, first)
+                    self.tokens_generated += 1
 
     def _dense_prefill_fn(self, batch: int, padded_len: int):
         key = (batch, padded_len)
@@ -455,7 +461,8 @@ class ServeEngine:
             self._dense_prefill[key] = fn
         return self._dense_prefill[key]
 
-    def _run_disagg_prefill(self, reqs: List[Request]) -> None:
+    def _run_disagg_prefill(self, reqs: List[Request], step: int
+                            ) -> None:
         """Whole-prompt prefill for all scheduled prompts as ONE dense
         batch on the prefill workers; each row's pages scatter into the
         decode workers' pool.  Rows are right-padded to a shared
@@ -471,77 +478,107 @@ class ServeEngine:
         Pb = 1
         while Pb < len(reqs):
             Pb *= 2
-        toks = np.zeros((Pb, padded), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, :r.prompt_len] = r.prompt
-        self.obs.record_compile("dense_prefill", (Pb, padded))
-        with self.obs.trace.span("serve.prefill", track="engine",
-                                 rows=len(reqs), bucket=Pb, padded=padded,
-                                 rids=[r.rid for r in reqs], disagg=True):
+        tr = self.obs.trace
+        with tr.span("serve.prefill.pack", track="engine", step=step):
+            toks = np.zeros((Pb, padded), np.int32)
+            for i, r in enumerate(reqs):
+                toks[i, :r.prompt_len] = r.prompt
+            self.obs.record_compile("dense_prefill", (Pb, padded))
+        with tr.span("serve.prefill", track="engine", rows=len(reqs),
+                     bucket=Pb, padded=padded, rids=[r.rid for r in reqs],
+                     disagg=True, step=step):
             task = self.mpmd_sched.submit(
                 self.prefill_group.name, self._dense_prefill_fn(Pb, padded),
                 self._params_prefill, jnp.asarray(toks))
             logits, pcaches = task.out
             # hand the KV pages to the decode workers (resharding device_put)
             dst = self.decode_group.sharding()
-            with self.obs.trace.span("serve.kv_transfer", track="engine",
-                                     rows=len(reqs)):
+            with tr.span("serve.kv_transfer", track="engine",
+                         rows=len(reqs)):
                 pcaches = jax.tree.map(lambda a: jax.device_put(a, dst),
                                        pcaches)
         self.prefill_calls += 1
         self.prefill_chunks += len(reqs)
         self.obs.metrics.counter("serve.prefill_calls").inc()
         self.obs.metrics.counter("serve.prefill_chunks").inc(len(reqs))
-        for i, req in enumerate(reqs):
-            S = req.prompt_len
-            self.pool.seat_prefill_caches(pcaches, req.table, S, row=i)
-            self.scheduler.on_prefill_chunk(req, S - req.prefill_done)
-            first = self._sample(logits[i, S - 1], req)
-            self.scheduler.on_prompt_complete(req, first)
-            self.tokens_generated += 1
+        with tr.span("serve.prefill.first_tokens", track="engine", step=step):
+            for i, req in enumerate(reqs):
+                S = req.prompt_len
+                self.pool.seat_prefill_caches(pcaches, req.table, S, row=i)
+                self.scheduler.on_prefill_chunk(req, S - req.prefill_done)
+                first = self._sample(logits[i, S - 1], req)
+                self.scheduler.on_prompt_complete(req, first)
+                self.tokens_generated += 1
 
     # ------------------------------------------------------------------
     # the engine iteration
     # ------------------------------------------------------------------
     def step(self) -> List[Tuple[int, int]]:
-        """One scheduler+compute iteration.  Returns [(rid, new token)]."""
-        plan = self.scheduler.schedule()
-        if plan.near_head or self._restore_prefetch.entries:
-            self._stage_restores(plan.near_head)
-        if self.layout.has_slot_state:
-            # fresh admissions must not inherit the previous occupant's
-            # recurrence (resumed requests were re-seated inside _restore,
-            # atomically with their pages)
-            for req in plan.admitted:
-                self.pool.zero_slot(req.slot)
-        events: List[Tuple[int, int]] = []
-        if plan.prefill:
-            # all scheduled chunks run in one batched call per group of
-            # prefill_batch rows (== one call per step at the defaults,
-            # where the scheduler budget never exceeds the row count)
-            gsz = self.scfg.prefill_batch
-            if (self.moe_dispatch == "gshard"
-                    and getattr(self.cfg, "moe", None) is not None):
-                # forced GShard capacity dispatch makes a row's output
-                # depend on its batch mates — keep the old one-request
-                # prefills (paged and disagg alike) rather than silently
-                # change outputs with batch composition
-                gsz = 1
-            for i in range(0, len(plan.prefill), gsz):
-                group = plan.prefill[i:i + gsz]
-                if self.prefill_group is not None:
-                    self._run_disagg_prefill(group)
-                else:
-                    self._run_prefill_batch(group)
-            for req in plan.prefill:
-                if req.generated:
-                    events.append((req.rid, req.generated[-1]))
+        """One scheduler+compute iteration.  Returns [(rid, new token)].
 
-        runners = [r for r in plan.decode
-                   if r.state is RequestState.RUNNING]
-        if runners:
-            B = self.scfg.max_slots
-            W = self.pcfg.max_blocks_per_req
+        While the tracer is enabled the iteration is one ``serve.step``
+        span (a profiler step annotation) whose children, each carrying
+        the same ``step`` index, split the host's time into phases:
+        ``serve.schedule``, ``serve.prefill.pack``, ``serve.prefill``
+        (the dispatch), ``serve.prefill.first_tokens``,
+        ``serve.decode.pack``, ``serve.decode`` (``.dispatch`` then
+        ``.wait``), ``serve.commit`` and ``serve.gauges``."""
+        tr = self.obs.trace
+        n = self.scheduler.steps                # this iteration's index
+        with tr.step_span("serve.step", n, track="engine") as root:
+            with tr.span("serve.schedule", track="engine", step=n):
+                plan = self.scheduler.schedule()
+                if plan.near_head or self._restore_prefetch.entries:
+                    self._stage_restores(plan.near_head)
+                if self.layout.has_slot_state:
+                    # fresh admissions must not inherit the previous
+                    # occupant's recurrence (resumed requests were re-seated
+                    # inside _restore, atomically with their pages)
+                    for req in plan.admitted:
+                        self.pool.zero_slot(req.slot)
+            events = self._step_prefill(plan.prefill, n)
+            runners = [r for r in plan.decode
+                       if r.state is RequestState.RUNNING]
+            root.set(prefill_rows=len(plan.prefill),
+                     decode_rows=len(runners))
+            if runners:
+                events += self._step_decode(runners, n)
+            with tr.span("serve.gauges", track="engine", step=n):
+                self._set_gauges()
+        return events
+
+    def _step_prefill(self, reqs: List[Request], step: int
+                      ) -> List[Tuple[int, int]]:
+        """This step's prompt chunks; the first tokens they produced."""
+        if not reqs:
+            return []
+        # all scheduled chunks run in one batched call per group of
+        # prefill_batch rows (== one call per step at the defaults, where
+        # the scheduler budget never exceeds the row count)
+        gsz = self.scfg.prefill_batch
+        if (self.moe_dispatch == "gshard"
+                and getattr(self.cfg, "moe", None) is not None):
+            # forced GShard capacity dispatch makes a row's output depend
+            # on its batch mates — keep the old one-request prefills (paged
+            # and disagg alike) rather than silently change outputs with
+            # batch composition
+            gsz = 1
+        for i in range(0, len(reqs), gsz):
+            group = reqs[i:i + gsz]
+            if self.prefill_group is not None:
+                self._run_disagg_prefill(group, step)
+            else:
+                self._run_prefill_batch(group, step)
+        return [(req.rid, req.generated[-1]) for req in reqs
+                if req.generated]
+
+    def _step_decode(self, runners: List[Request], step: int
+                     ) -> List[Tuple[int, int]]:
+        """One token for every runner; returns [(rid, token)]."""
+        tr = self.obs.trace
+        B = self.scfg.max_slots
+        W = self.pcfg.max_blocks_per_req
+        with tr.span("serve.decode.pack", track="engine", step=step):
             tokens = np.zeros((B, 1), np.int32)
             positions = np.zeros((B,), np.int32)
             tables = np.zeros((B, W), np.int32)
@@ -554,13 +591,14 @@ class ServeEngine:
             self.obs.record_compile("paged_decode", (B, W))
             self.obs.metrics.counter(
                 f"serve.kernels.decode.{self.kernel_path}").inc()
-            t_dec = time.perf_counter()
-            with self.obs.trace.span("serve.decode", track="engine",
-                                     runners=len(runners)):
+        with tr.span("serve.decode", track="engine", runners=len(runners),
+                     step=step):
+            with tr.span("serve.decode.dispatch", track="engine", step=step):
                 logits, self.pool.state = self._decode_step(
                     self.params, jnp.asarray(tokens), jnp.asarray(positions),
                     self.pool.state, jnp.asarray(tables),
                     jnp.asarray(slot_mask))
+            with tr.span("serve.decode.wait", track="engine", step=step):
                 if all(r.temperature <= 0 and not r.capture_logprobs
                        for r in runners):
                     # batched greedy: one device op + one transfer for the
@@ -577,16 +615,13 @@ class ServeEngine:
                 else:
                     picks = {r.slot: self._sample(logits[r.slot, -1], r)
                              for r in runners}
-            # one decode step advances every runner one token: the step's
-            # wall time IS each seated request's inter-token latency
-            self.obs.metrics.histogram("serve.itl_s").observe(
-                time.perf_counter() - t_dec)
+        events = []
+        with tr.span("serve.commit", track="engine", step=step):
             for r in runners:
                 tok = picks[r.slot]
                 self.scheduler.on_decode_token(r, tok)
                 self.tokens_generated += 1
                 events.append((r.rid, tok))
-        self._set_gauges()
         return events
 
     def _set_gauges(self) -> None:

@@ -94,7 +94,12 @@ class Request:
     spilled_blocks: int = 0                   # pages parked in the cold tier
     null_prefix: int = 0                      # leading window-freed table slots
     t_first_token: Optional[float] = None
+    t_last_token: Optional[float] = None      # inter-token latency stamp
     t_finish: Optional[float] = None
+    # engine steps (schedule() calls) from admission to the first token,
+    # both counted: 1 = admitted and prefilled whole in one step
+    admit_step: Optional[int] = None
+    prefill_steps: Optional[int] = None
 
     @property
     def prompt_len(self) -> int:
@@ -171,6 +176,8 @@ class ContinuousScheduler:
         # HyperTrace hub: the runtime passes its own; a bare scheduler
         # (unit tests) gets a private one so counters stay scoped
         self.obs = obs if obs is not None else Observability()
+        # observed once per generated token: fetched from the registry once
+        self._itl = self.obs.metrics.histogram("serve.itl_s")
         self.blocks = blocks
         self.block_size = block_size
         self.max_blocks_per_req = max_blocks_per_req
@@ -194,6 +201,7 @@ class ContinuousScheduler:
         self.active: List[Request] = []    # PREFILLING + RUNNING, FCFS order
         self.requests: Dict[int, Request] = {}
         self._rid = itertools.count()
+        self.steps = 0                     # schedule() calls so far
         self._free_slots = list(range(cfg.max_slots - 1, -1, -1))
         self.counters = {"preemptions": 0, "prefix_hits": 0, "rejected": 0}
 
@@ -263,6 +271,7 @@ class ContinuousScheduler:
 
     # -- the per-iteration decision ----------------------------------------
     def schedule(self) -> StepPlan:
+        self.steps += 1
         plan = StepPlan()
         self._admit(plan)
         self._plan_prefill(plan)
@@ -331,6 +340,7 @@ class ContinuousScheduler:
             self.active.append(req)
             plan.admitted.append(req)
             req.t_admit = self._clock()
+            req.admit_step = self.steps
             wait = req.t_admit - req.t_enqueue
             self.obs.metrics.histogram("serve.queue_wait_s").observe(
                 max(wait, 0.0))
@@ -435,7 +445,9 @@ class ContinuousScheduler:
         self._window_free(req, req.prefill_done)
 
     def _note_first_token(self, req: Request) -> None:
-        req.t_first_token = self._clock()
+        req.t_first_token = req.t_last_token = self._clock()
+        if req.admit_step is not None:
+            req.prefill_steps = self.steps - req.admit_step + 1
         ttft = req.t_first_token - req.t_enqueue
         self.obs.metrics.histogram("serve.ttft_s").observe(max(ttft, 0.0))
         self.obs.trace.instant("serve.first_token", rid=req.rid,
@@ -451,6 +463,12 @@ class ContinuousScheduler:
         req.generated.append(token)
         if req.t_first_token is None:
             self._note_first_token(req)
+        else:
+            # the gap since this request's previous token: what its user
+            # waits between tokens, whatever else the step ran
+            now = self._clock()
+            self._itl.observe(max(now - req.t_last_token, 0.0))
+            req.t_last_token = now
         # the next decode step writes + queries at position total_len - 1
         if req.state is RequestState.RUNNING:
             self._window_free(req, req.total_len - 1)

@@ -70,7 +70,8 @@ def make_train_step(cfg, mesh: Optional[Mesh], plan: hypershard.ShardingPlan,
                     multimodal: bool = False, unroll: bool = False):
     """Returns (step_fn, shardings dict). step(params, opt, batch)->(p,o,metrics)."""
 
-    def step(params, opt_state, batch):
+    # the function's name names the XLA module: jit_train_step
+    def train_step(params, opt_state, batch):
         ctx = use_mesh(mesh) if mesh is not None else _null()
         with ctx:
             pe = batch.get("prefix_embeds") if multimodal else None
@@ -85,7 +86,8 @@ def make_train_step(cfg, mesh: Optional[Mesh], plan: hypershard.ShardingPlan,
         return new_params, new_opt, metrics
 
     if mesh is None:
-        return jax.jit(step, donate_argnums=(0, 1) if donate else ()), {}
+        return jax.jit(train_step,
+                       donate_argnums=(0, 1) if donate else ()), {}
 
     pshapes = jax.eval_shape(lambda: M.init_model(cfg, jax.random.PRNGKey(0)))
     param_sh = hypershard.make_param_shardings(mesh, pshapes, plan)
@@ -112,7 +114,7 @@ def make_train_step(cfg, mesh: Optional[Mesh], plan: hypershard.ShardingPlan,
     # streaming remains available via offload.streamed_apply (per-layer
     # host arguments, unrolled), used by the offload benchmarks.
     step_jit = jax.jit(
-        step,
+        train_step,
         in_shardings=(param_sh, opt_in, batch_sh),
         out_shardings=(param_sh, opt_in, None),
         donate_argnums=(0, 1) if donate else (),

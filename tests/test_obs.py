@@ -10,6 +10,10 @@ exact per-request instant sequence (submit -> admit -> first_token ->
 [preempt -> resume ->] finish) plus spill/restore spans, and
 ``ServeAPI.stats()`` / ``stream(final_meta=True)`` must surface the
 percentiles and per-request lifecycle records built on the registry.
+
+Profiler sink: an enabled tracer enters one profiler annotation per span
+(and lands in a real CPU profiler trace), a disabled one touches none,
+and each engine step is one ``serve.step`` span holding its phases.
 """
 import dataclasses
 import math
@@ -306,3 +310,133 @@ def test_stream_final_meta_lifecycle_record(qwen_f32):
     assert meta["queue_wait_s"] >= 0
     assert meta["ttft_s"] >= meta["queue_wait_s"]
     assert meta["latency_s"] >= meta["ttft_s"]
+
+
+# ------------------------------------------------ the profiler sink
+
+class _FakeAnnotation:
+    """Records what a profiler annotation is asked to do."""
+
+    def __init__(self, log, name, **kw):
+        self.log, self.name, self.kw = log, name, kw
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.kw))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.kw))
+
+
+def _fake_annotations(log):
+    return (lambda name: _FakeAnnotation(log, name),
+            lambda name, **kw: _FakeAnnotation(log, name, **kw))
+
+
+def test_enabled_tracer_enters_a_profiler_annotation_per_span():
+    log = []
+    tr = Tracer(annotations=_fake_annotations(log)).enable()
+    with tr.step_span("loop", 7, rid=1) as root:
+        with tr.span("inner"):
+            pass
+        root.set(rows=2)
+    assert log == [("enter", "loop", {"step_num": 7}),
+                   ("enter", "inner", {}), ("exit", "inner", {}),
+                   ("exit", "loop", {"step_num": 7})]
+    inner, outer = tr.events()
+    assert outer["args"] == {"rid": 1, "step": 7, "rows": 2}
+    assert [inner["name"], outer["name"]] == ["inner", "loop"]
+    # after-the-fact spans stay in the ring alone
+    tr.complete("late", 0, 10)
+    assert len(log) == 4 and tr.events()[-1]["name"] == "late"
+
+
+def test_disabled_tracer_touches_no_annotation():
+    log = []
+    tr = Tracer(annotations=_fake_annotations(log))
+    assert tr.span("x", rid=1) is NOOP_SPAN
+    assert tr.step_span("x", 3) is NOOP_SPAN
+    with tr.step_span("x", 3) as s:
+        s.set(rows=1)
+    assert log == [] and tr.events() == []
+
+
+def test_import_repro_obs_pulls_in_no_jax():
+    from conftest import run_subprocess
+    out = run_subprocess(
+        "import sys, repro.obs\n"
+        "repro.obs.Tracer().span('x')\n"
+        "print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))",
+        devices=1, timeout=120)
+    assert out.strip() == "[]"
+
+
+def test_enabled_spans_land_in_the_profiler_trace(tmp_path):
+    """On the profiler's clock, in its host plane, under their own names."""
+    from jax.profiler import ProfileData
+    tr = Tracer().enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.step_span("obs.test.step", 5):
+            with tr.span("obs.test.phase"):
+                jax.numpy.ones(3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    got = {ev.name: ev for plane in ProfileData.from_file(str(path)).planes
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("obs.test.")}
+    step, phase = got["obs.test.step"], got["obs.test.phase"]
+    assert dict(step.stats)["step_num"] == 5
+    assert step.start_ns <= phase.start_ns
+    assert (phase.start_ns + phase.duration_ns
+            <= step.start_ns + step.duration_ns)
+
+
+# ------------------------------------------- the engine step's span tree
+
+PHASES = {"serve.schedule", "serve.prefill.pack", "serve.prefill",
+          "serve.prefill.first_tokens", "serve.decode.pack", "serve.decode",
+          "serve.decode.dispatch", "serve.decode.wait", "serve.commit",
+          "serve.gauges"}
+
+
+def test_engine_step_span_tree_and_prefill_steps(qwen_f32):
+    cfg, params = qwen_f32
+    # one 4-token chunk per step: the 10-token prompt prefills in steps
+    # 0-2, the 4-token one admitted beside it in step 3
+    scfg = ServeConfig(block_size=4, num_blocks=40, max_blocks_per_req=8,
+                       max_slots=2, prefill_chunk=4,
+                       prefill_chunks_per_step=1, prefill_batch=1)
+    serve = HyperServe(cfg, params, serve_cfg=scfg)
+    tr = serve.obs().trace.enable()
+    rids = [serve.submit(list(range(1, 11)), 3),
+            serve.submit(list(range(20, 24)), 3)]
+    n = 0
+    while serve.engine.scheduler.has_work():
+        serve.step_once()
+        n += 1
+    assert [serve.request_meta(r)["prefill_steps"] for r in rids] == [3, 4]
+
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    roots = [e for e in spans if e["name"] == "serve.step"]
+    assert [r["args"]["step"] for r in roots] == list(range(n))
+    assert [(r["args"]["prefill_rows"], r["args"]["decode_rows"])
+            for r in roots[:5]] == [(1, 0), (1, 0), (1, 0), (1, 1), (0, 2)]
+    seen = set()
+    for e in spans:
+        if e["name"] not in PHASES:
+            continue
+        seen.add(e["name"])
+        root = roots[e["args"]["step"]]
+        assert root["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= root["ts"] + root["dur"] + 1e-3
+    assert seen == PHASES
+    # the prefill dispatch keeps its name and the args the benchmark reads
+    prefills = [e for e in spans if e["name"] == "serve.prefill"]
+    assert len(prefills) == 4
+    assert [p["args"]["rids"] for p in prefills] == [[rids[0]]] * 3 + [
+        [rids[1]]]
+    assert all(p["args"]["rows"] == 1 and p["args"]["bucket"] == 1
+               for p in prefills)

@@ -295,3 +295,33 @@ def test_restore_failure_returns_the_seat():
     sched.schedule()                          # resume attempt fails
     assert req.state is RequestState.PREEMPTED and req.slot == -1
     assert len(sched._free_slots) == 2        # seat not leaked
+
+
+def test_inter_token_latency_is_the_gap_between_a_requests_tokens():
+    sched, _ = make_sched(max_slots=2, chunks_per_step=2)
+    a, b = sched.submit([1] * 4, 4), sched.submit([2] * 4, 4)
+    drive_prefill(sched, sched.schedule())
+    gaps, last = [], {r.rid: r.t_first_token for r in (a, b)}
+    sched.schedule()
+    for req, tok in ((a, 5), (b, 5), (a, 6)):
+        sched.on_decode_token(req, tok)     # each clock read is one tick
+        gaps.append(req.t_last_token - last[req.rid])
+        last[req.rid] = req.t_last_token
+    itl = sched.obs.metrics.histogram("serve.itl_s")
+    # one gap per token after the first, each its request's own
+    assert (itl.count, itl.sum, itl.min, itl.max) == (
+        3, sum(gaps), min(gaps), max(gaps))
+    # first tokens at ticks 4 and 5, then tokens at 6, 7 and 8
+    assert gaps == [2, 2, 2]
+
+
+def test_prefill_steps_count_engine_steps_from_admission_to_first_token():
+    # one chunk per step: a 10-token prompt takes three steps, the 4-token
+    # prompt admitted beside it waits for them and prefills in the fourth
+    sched, _ = make_sched(max_slots=2, chunks_per_step=1)
+    a, b = sched.submit([1] * 10, 4), sched.submit([2] * 4, 4)
+    for _ in range(4):
+        drive_prefill(sched, sched.schedule())
+    assert (a.prefill_steps, b.prefill_steps) == (3, 4)
+    late = sched.submit([3] * 4, 4)         # queued until a seat frees
+    assert late.prefill_steps is None
