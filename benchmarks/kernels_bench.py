@@ -75,8 +75,8 @@ def paged_cases(host):
     lengths = [100, 37, 128, 9]              # partial last pages everywhere
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, 1, H, D)) * 0.3
-    kp = jax.random.normal(ks[1], (N, bs, KV, D)) * 0.3
-    vp = jax.random.normal(ks[2], (N, bs, KV, D)) * 0.3
+    kp = jax.random.normal(ks[1], (N, bs, KV * D)) * 0.3       # lane-dense
+    vp = jax.random.normal(ks[2], (N, bs, KV * D)) * 0.3
     tables = jnp.arange(1, 1 + B * W, dtype=jnp.int32).reshape(B, W) % N
     lens = jnp.asarray(lengths, jnp.int32)
     pv = PM.decode_pages_visited(lengths, block_size=bs)

@@ -255,9 +255,10 @@ def kernel_errors(cfg, seed, dtype):
     err = lambda a, b: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
     hi = functools.partial(jax.default_matmul_precision, "highest")
     errs = {}
-    # the table walk may visit any page: distinct random pages per row
+    # the table walk may visit any page: distinct random pages per row;
+    # pages are lane-dense, every KV head of a row side by side
     N, B, P, C, S = POOL_BLOCKS, SEATS, PREFILL_ROWS, CHUNK, 1024
-    kp, vp = rnd(N, BLOCK, KV, D), rnd(N, BLOCK, KV, D)
+    kp, vp = rnd(N, BLOCK, KV * D), rnd(N, BLOCK, KV * D)
     tab = jnp.asarray(rng.permutation(np.arange(1, N))[:B * TABLE]
                       .reshape(B, TABLE), jnp.int32)
     lens = jnp.asarray(rng.integers(1, TABLE * BLOCK + 1, size=B), jnp.int32)

@@ -227,7 +227,8 @@ def explain(plan: HyperPlan, cfg, layout: Optional[Layout] = None, *,
                 for name, leaf in zip(spaths, sleaves):
                     path = f"{seg.name}/{j}.{spec.kind}/{name}"
                     strat, note, fbs = hypershard.derive_pool(
-                        path, tuple(leaf.shape), layout, splan)
+                        path, tuple(leaf.shape), layout, splan,
+                        kv_heads=cfg.num_kv_heads)
                     leaves.append(LeafReport(
                         "state", path, tuple(leaf.shape),
                         strat.partition_spec(), kind_desc, note, fbs))

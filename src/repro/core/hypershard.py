@@ -304,19 +304,20 @@ def cache_strategy(path: str, shape: Tuple[int, ...], layout: Layout,
 
 
 def derive_pool(path: str, shape: Tuple[int, ...], layout: Layout,
-                plan: ShardingPlan):
+                plan: ShardingPlan, *, kv_heads: int):
     """Serving StatePool leaf derivation: ``(ShardStrategy, note, fallbacks)``.
 
     StatePool leaves (dim0 is always the stacked-layer axis):
 
-      k/v           (L, N_blocks, block, KV, hd)  paged attention pool
+      k/v           (L, N_blocks, block, KV*hd)   paged attention pool
       ckv / krope   (L, N_blocks, block, R)       paged MLA latent pool
       state         (L, slots, H, P, N) or (L, slots, W)  per-slot SSD/RG-LRU
       conv          (L, slots, K-1, C)            per-slot causal-conv tail
 
     Paged pools are shared by every request, so they replicate over the
-    data axes; the KV-head dim shards over tp when divisible (the
-    ``cache_strategy`` rule, pool edition).  MLA latents have no head dim
+    data axes; the lane-dense KV-head dim shards over tp when the
+    ``kv_heads`` it merges divide by tp (the ``cache_strategy`` rule,
+    pool edition: a shard holds whole heads).  MLA latents have no head dim
     — they replicate.  Per-slot dense state shards its head/channel dim
     over tp when divisible, mirroring the dense decode-cache derivation.
 
@@ -331,18 +332,19 @@ def derive_pool(path: str, shape: Tuple[int, ...], layout: Layout,
     tp_n = math.prod(layout.axis_size(a) for a in tp) if tp else 1
     leaf = path.rsplit("/", 1)[-1]
 
-    def try_tp(dim_idx: int, what: str):
+    def try_tp(dim_idx: int, what: str, count: Optional[int] = None):
         if not tp:
             return
-        if shape[dim_idx] % tp_n == 0:
+        count = shape[dim_idx] if count is None else count
+        if count % tp_n == 0:
             entries[dim_idx] = _fit(tp)
             notes.append(f"{what}/tp")
         else:
-            fallbacks.append(f"{what} {shape[dim_idx]} % {tp_n} != 0 -> "
+            fallbacks.append(f"{what} {count} % {tp_n} != 0 -> "
                              f"{tp} unplaced, replicated")
 
     if leaf in ("k", "v"):
-        try_tp(3, "kv-heads")
+        try_tp(3, "kv-heads", kv_heads)
     elif leaf in ("ckv", "krope"):
         notes.append("latent pool replicated (rank shared across heads)")
     elif leaf == "state" and ndim >= 3:

@@ -8,9 +8,10 @@ disappears: the grid iterates ``(batch, page)`` and the K/V
 ``BlockSpec`` index_maps index the *pool's block axis through the
 scalar-prefetched block table* (``pltpu.PrefetchScalarGridSpec``), so each
 page (every KV head of it) streams HBM→VMEM exactly once, straight from
-the pool, and the whole decode step is ONE kernel.  A block of one page
-with all its KV heads keeps the block's last two dims equal to the pool's,
-which is what the TPU tiling accepts; the kernel loops the KV heads.
+the pool, and the whole decode step is ONE kernel.  Pages are lane-dense,
+``(block_size, KV*D)``: a block of one page keeps the block's last two
+dims equal to the pool's, which is what the TPU tiling accepts, and the
+kernel loops the KV heads over lane slices of it.
 
 Two variants share the flash-style running-softmax accumulator:
 
@@ -67,12 +68,13 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         valid = pos < length
         if window is not None:
             valid &= pos >= length - window
-        # one page holds every KV head; the G query heads of each KV head
-        # attend its (bs, D) slice
+        # one page row holds every KV head side by side on the lanes; the
+        # G query heads of each KV head attend its (bs, D) lane slice
+        D, Dv = q_ref.shape[3], o_ref.shape[3]
         for h in range(kv):
-            q = q_ref[0, h]                   # (G, D)
-            k = k_ref[0, :, h, :]             # (bs, D)
-            v = v_ref[0, :, h, :]             # (bs, Dv)
+            q = q_ref[0, h]                           # (G, D)
+            k = k_ref[0, :, h * D:(h + 1) * D]        # (bs, D)
+            v = v_ref[0, :, h * Dv:(h + 1) * Dv]      # (bs, Dv)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
             s = jnp.where(valid, s, NEG_INF)
@@ -96,8 +98,8 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_decode_attention(
     q: jax.Array,             # (B, 1, H, D) — one query token per slot
-    k_pool: jax.Array,        # (N_blocks, block_size, KV, D)
-    v_pool: jax.Array,        # (N_blocks, block_size, KV, Dv)
+    k_pool: jax.Array,        # (N_blocks, block_size, KV*D), lane-dense
+    v_pool: jax.Array,        # (N_blocks, block_size, KV*Dv)
     block_tables: jax.Array,  # (B, W) int32; padding entries -> null block
     lengths: jax.Array,       # (B,) int32 valid positions (= pos + 1)
     *,
@@ -108,7 +110,8 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Fused paged flash decode.  Returns (B, 1, H, Dv) in q.dtype."""
     B, _, H, D = q.shape
-    KV, Dv = k_pool.shape[2], v_pool.shape[3]
+    KV = k_pool.shape[2] // D
+    Dv = v_pool.shape[2] // KV
     assert H % KV == 0, (H, KV)
     G = H // KV
     W = block_tables.shape[1]
@@ -125,10 +128,10 @@ def paged_decode_attention(
             # the in-kernel block-table walk: the pool's block axis is
             # indexed through the prefetched table, one whole page (all KV
             # heads, so the block's last two dims are the pool's) per step
-            pl.BlockSpec((1, block_size, KV, D),
-                         lambda b, w, tab, lens: (tab[b, w], 0, 0, 0)),
-            pl.BlockSpec((1, block_size, KV, Dv),
-                         lambda b, w, tab, lens: (tab[b, w], 0, 0, 0)),
+            pl.BlockSpec((1, block_size, KV * D),
+                         lambda b, w, tab, lens: (tab[b, w], 0, 0)),
+            pl.BlockSpec((1, block_size, KV * Dv),
+                         lambda b, w, tab, lens: (tab[b, w], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, KV, G, Dv),
                                lambda b, w, tab, lens: (b, 0, 0, 0)),

@@ -9,8 +9,8 @@ price for garbage, and the pool is read twice (gather + flash).
 This kernel consumes the scheduler's per-row ``(start, limit)`` vectors
 directly.  The grid iterates ``(row, page)`` with the page axis walked
 through the scalar-prefetched block table (one HBM→VMEM stream per page,
-all KV heads at once, straight from the pool; the kernel loops the KV
-heads), and ``pl.when`` skips the pages the
+all KV heads at once, straight from the lane-dense pool; the kernel
+loops the KV heads over lane slices), and ``pl.when`` skips the pages the
 composed path merely masks:
 
   - dead rows (``limit == 0``) — filler never touches the MXU;
@@ -64,12 +64,14 @@ def _prefill_kernel(tab_ref, start_ref, limit_ref, q_ref, k_ref, v_ref,
         valid = kp <= qp
         if window is not None:
             valid &= qp - kp < window
-        # one page holds every KV head; each head's G query heads are
-        # stacked on the row axis, (G, C, D) -> (G*C, D), for one matmul
+        # one page row holds every KV head side by side on the lanes; each
+        # head's G query heads are stacked on the row axis, (G, C, D) ->
+        # (G*C, D), for one matmul against its (bs, D) lane slice
+        Dv = o_ref.shape[4]
         for h in range(kv):
             q = q_ref[0, h].reshape(G * C, D)
-            k = k_ref[0, :, h, :]             # (bs, D)
-            v = v_ref[0, :, h, :]             # (bs, Dv)
+            k = k_ref[0, :, h * D:(h + 1) * D]        # (bs, D)
+            v = v_ref[0, :, h * Dv:(h + 1) * Dv]      # (bs, Dv)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32).reshape(G, C, bs) * scale
@@ -95,8 +97,8 @@ def _prefill_kernel(tab_ref, start_ref, limit_ref, q_ref, k_ref, v_ref,
 
 def ragged_prefill_attention(
     q: jax.Array,             # (P, C, H, D) — one prompt chunk per row
-    k_pool: jax.Array,        # (N_blocks, block_size, KV, D)
-    v_pool: jax.Array,        # (N_blocks, block_size, KV, Dv)
+    k_pool: jax.Array,        # (N_blocks, block_size, KV*D), lane-dense
+    v_pool: jax.Array,        # (N_blocks, block_size, KV*Dv)
     block_tables: jax.Array,  # (P, W) int32
     starts: jax.Array,        # (P,) int32 absolute position of chunk col 0
     limits: jax.Array,        # (P,) int32 true prompt length; 0 = filler
@@ -108,7 +110,8 @@ def ragged_prefill_attention(
 ) -> jax.Array:
     """Fused ragged chunked-prefill flash.  Returns (P, C, H, Dv)."""
     P, C0, H, D = q.shape
-    KV, Dv = k_pool.shape[2], v_pool.shape[3]
+    KV = k_pool.shape[2] // D
+    Dv = v_pool.shape[2] // KV
     assert H % KV == 0, (H, KV)
     G = H // KV
     W = block_tables.shape[1]
@@ -128,10 +131,10 @@ def ragged_prefill_attention(
         in_specs=[
             pl.BlockSpec((1, KV, G, C, D),
                          lambda p, w, tab, st, lm: (p, 0, 0, 0, 0)),
-            pl.BlockSpec((1, block_size, KV, D),
-                         lambda p, w, tab, st, lm: (tab[p, w], 0, 0, 0)),
-            pl.BlockSpec((1, block_size, KV, Dv),
-                         lambda p, w, tab, st, lm: (tab[p, w], 0, 0, 0)),
+            pl.BlockSpec((1, block_size, KV * D),
+                         lambda p, w, tab, st, lm: (tab[p, w], 0, 0)),
+            pl.BlockSpec((1, block_size, KV * Dv),
+                         lambda p, w, tab, st, lm: (tab[p, w], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, KV, G, C, Dv),
                                lambda p, w, tab, st, lm: (p, 0, 0, 0, 0)),

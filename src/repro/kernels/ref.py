@@ -168,12 +168,13 @@ def decode_attention(
 # block-table-walking kernels in paged_decode_attention.py /
 # ragged_prefill_attention.py.  Deliberately written as the composed
 # lowering — pool[block_tables] gather then the dense oracle above — so
-# fused-vs-composed parity is provable by construction.
+# fused-vs-composed parity is provable by construction.  Pools are
+# lane-dense: a page row holds every KV head side by side, (bs, KV*D).
 # ---------------------------------------------------------------------------
 def paged_decode_attention(
     q: jax.Array,             # (B, 1, H, Dk)
-    k_pool: jax.Array,        # (N_blocks, block_size, KV, Dk)
-    v_pool: jax.Array,        # (N_blocks, block_size, KV, Dv)
+    k_pool: jax.Array,        # (N_blocks, block_size, KV*Dk)
+    v_pool: jax.Array,        # (N_blocks, block_size, KV*Dv)
     block_tables: jax.Array,  # (B, W) int32
     lengths: jax.Array,       # (B,) int32
     *,
@@ -183,8 +184,9 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Paged decode oracle. Returns (B, 1, H, Dv) in q.dtype."""
     B, W = block_tables.shape
-    KV, Dk = k_pool.shape[2], k_pool.shape[3]
-    Dv = v_pool.shape[3]
+    Dk = q.shape[-1]
+    KV = k_pool.shape[2] // Dk
+    Dv = v_pool.shape[2] // KV
     k_seq = k_pool[block_tables].reshape(B, W * block_size, KV, Dk)
     v_seq = v_pool[block_tables].reshape(B, W * block_size, KV, Dv)
     return decode_attention(q, k_seq, v_seq, lengths, scale=scale,
@@ -193,8 +195,8 @@ def paged_decode_attention(
 
 def ragged_prefill_attention(
     q: jax.Array,             # (P, C, H, Dk)
-    k_pool: jax.Array,        # (N_blocks, block_size, KV, Dk)
-    v_pool: jax.Array,        # (N_blocks, block_size, KV, Dv)
+    k_pool: jax.Array,        # (N_blocks, block_size, KV*Dk)
+    v_pool: jax.Array,        # (N_blocks, block_size, KV*Dv)
     block_tables: jax.Array,  # (P, W) int32
     starts: jax.Array,        # (P,) int32
     limits: jax.Array,        # (P,) int32; 0 = filler row
@@ -209,9 +211,9 @@ def ragged_prefill_attention(
     discarded upstream, and the fused kernel skips them entirely.
     """
     P, W = block_tables.shape
-    C = q.shape[1]
-    KV, Dk = k_pool.shape[2], k_pool.shape[3]
-    Dv = v_pool.shape[3]
+    C, Dk = q.shape[1], q.shape[-1]
+    KV = k_pool.shape[2] // Dk
+    Dv = v_pool.shape[2] // KV
     k_seq = k_pool[block_tables].reshape(P, W * block_size, KV, Dk)
     v_seq = v_pool[block_tables].reshape(P, W * block_size, KV, Dv)
 

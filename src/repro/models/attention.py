@@ -130,17 +130,38 @@ def attn_prefill(p, x, positions, cfg, *, window: Optional[int] = None):
     return out.reshape(B, S, -1) @ p["wo"], cache
 
 
-def attn_decode_paged(p, x, positions, cfg, kv, block_tables, *,
+def _layer_pages(kv, layer):
+    """Layer ``layer``'s pages inside the stacked lane-dense pool.
+
+    ``kv``: {"k","v"} leaves (L, N_blocks, block, KV*hd).  Returns the
+    leaves viewed as one (L*N_blocks, block, KV*hd) pool (merging the two
+    leading dims is a bitcast, so the view is the pool itself) and the
+    page offset ``layer * N_blocks`` that maps the layer's block ids,
+    its null block 0 included, into that view.
+    """
+    L, N = kv["k"].shape[:2]
+    flat = {n: a.reshape((L * N,) + a.shape[2:]) for n, a in kv.items()}
+    return flat, layer * N
+
+
+def _unflatten(flat, like):
+    return {n: flat[n].reshape(like[n].shape) for n in flat}
+
+
+def attn_decode_paged(p, x, positions, cfg, kv, block_tables, *, layer,
                       block_size: int, window: Optional[int] = None,
                       kernels: str = "composed"):
     """One-token decode against the paged KV pool (HyperServe).
 
     x: (B, 1, D) — one token per batch slot; ``positions``: (B,) absolute
     write position of each slot's token (continuous batching: every slot
-    is at a different position).  ``kv``: {"k","v"} pool leaves
-    (N_blocks, block, KV, hd) — the stacked-layer axis has already been
-    sliced off by the caller's scan.  ``block_tables``: (B, W) int32; row
-    padding entries point at the null block and are never unmasked.
+    is at a different position).  ``kv``: {"k","v"} pool leaves of EVERY
+    layer, (L, N_blocks, block, KV*hd) — lane-dense pages, updated in
+    place: the token scatter and the attention read layer ``layer``
+    (traced) through :func:`_layer_pages`' view, so no layer slice or
+    pool copy is ever made.  ``block_tables``: (B, W) int32 layer-local
+    block ids; row padding entries point at the null block and are never
+    unmasked.
 
     ``window`` (LOCAL_ATTN): keys below ``pos + 1 - window`` are masked,
     so the runtime may free their blocks (table entries repointed at the
@@ -154,23 +175,25 @@ def attn_decode_paged(p, x, positions, cfg, kv, block_tables, *,
     B = x.shape[0]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     q, k, v = _qkv(p, x, cfg, positions[:, None])
+    flat, base = _layer_pages(kv, layer)
+    tables = block_tables + base
     bidx = jnp.take_along_axis(
-        block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
+        tables, (positions // block_size)[:, None], axis=1)[:, 0]
     off = positions % block_size
-    k_pool = kv["k"].at[bidx, off].set(k[:, 0])
-    v_pool = kv["v"].at[bidx, off].set(v[:, 0])
+    k_pool = flat["k"].at[bidx, off].set(k[:, 0].reshape(B, KV * hd))
+    v_pool = flat["v"].at[bidx, off].set(v[:, 0].reshape(B, KV * hd))
     lengths = (positions + 1).astype(jnp.int32)
     if kernels == "fused":
-        out = ops.paged_decode_attention(q, k_pool, v_pool, block_tables,
+        out = ops.paged_decode_attention(q, k_pool, v_pool, tables,
                                          lengths, block_size=block_size,
                                          window=window)
     else:
-        W = block_tables.shape[1]
-        k_seq = k_pool[block_tables].reshape(B, W * block_size, KV, hd)
-        v_seq = v_pool[block_tables].reshape(B, W * block_size, KV, hd)
+        W = tables.shape[1]
+        k_seq = k_pool[tables].reshape(B, W * block_size, KV, hd)
+        v_seq = v_pool[tables].reshape(B, W * block_size, KV, hd)
         out = ops.decode_attention(q, k_seq, v_seq, lengths, window=window)
     y = out.reshape(B, 1, H * hd) @ p["wo"]
-    return y, {"k": k_pool, "v": v_pool}
+    return y, _unflatten({"k": k_pool, "v": v_pool}, kv)
 
 
 def paged_chunk_indices(positions, limits, block_tables, *, block_size: int):
@@ -207,18 +230,20 @@ def flash_rows(q, k, v, starts, *, window=None, scale=None):
 
 
 def attn_prefill_paged(p, x, starts, limits, cfg, kv, block_tables, *,
-                       block_size: int, window: Optional[int] = None,
+                       layer, block_size: int, window: Optional[int] = None,
                        kernels: str = "composed"):
     """One batched chunked-prefill step against the paged KV pool.
 
     x: (P, C, D) — one prompt chunk per row, row ``r``'s first token at
     absolute position ``starts[r]`` (traced vector).  Writes every row's
-    K/V into its own pages in one scatter, then attends each row's chunk
-    queries over that row's gathered table (history + chunk) with
-    per-row ``q_offset=starts[r]`` causal masking — exact chunked
-    prefill, P requests per kernel launch.  ``limits``: (P,) true prompt
-    lengths — positions >= the limit are padding (null-block writes,
-    outputs ignored); fully-padded rows (limit 0) are scheduler filler.
+    K/V into its own pages of layer ``layer`` in one in-place scatter
+    (``kv`` holds every layer's lane-dense leaves, as in
+    :func:`attn_decode_paged`), then attends each row's chunk queries
+    over that row's table (history + chunk) with per-row
+    ``q_offset=starts[r]`` causal masking — exact chunked prefill, P
+    requests per kernel launch.  ``limits``: (P,) true prompt lengths —
+    positions >= the limit are padding (null-block writes, outputs
+    ignored); fully-padded rows (limit 0) are scheduler filler.
     ``block_tables``: (P, W) per-row tables.  ``window`` applies the
     LOCAL_ATTN sliding window to the gathered keys.
     """
@@ -226,22 +251,25 @@ def attn_prefill_paged(p, x, starts, limits, cfg, kv, block_tables, *,
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     positions = starts[:, None] + jnp.arange(C)[None, :]     # (P, C)
     q, k, v = _qkv(p, x, cfg, positions)
+    flat, base = _layer_pages(kv, layer)
+    tables = block_tables + base
     bidx, off, _ = paged_chunk_indices(positions, limits, block_tables,
                                        block_size=block_size)
-    k_pool = kv["k"].at[bidx, off].set(k)
-    v_pool = kv["v"].at[bidx, off].set(v)
+    bidx = bidx + base
+    k_pool = flat["k"].at[bidx, off].set(k.reshape(P, C, KV * hd))
+    v_pool = flat["v"].at[bidx, off].set(v.reshape(P, C, KV * hd))
     if kernels == "fused":
         out = ops.ragged_prefill_attention(
-            q, k_pool, v_pool, block_tables,
+            q, k_pool, v_pool, tables,
             starts.astype(jnp.int32), limits.astype(jnp.int32),
             block_size=block_size, window=window)
     else:
-        W = block_tables.shape[1]
-        k_seq = k_pool[block_tables].reshape(P, W * block_size, KV, hd)
-        v_seq = v_pool[block_tables].reshape(P, W * block_size, KV, hd)
+        W = tables.shape[1]
+        k_seq = k_pool[tables].reshape(P, W * block_size, KV, hd)
+        v_seq = v_pool[tables].reshape(P, W * block_size, KV, hd)
         out = flash_rows(q, k_seq, v_seq, starts, window=window)
     y = out.reshape(P, C, H * hd) @ p["wo"]
-    return y, {"k": k_pool, "v": v_pool}
+    return y, _unflatten({"k": k_pool, "v": v_pool}, kv)
 
 
 def attn_decode(p, x, pos, cfg, cache, *, window: Optional[int] = None):

@@ -64,11 +64,11 @@ class MixerSpec:
     init_state: Callable       # (cfg, *, num_blocks, block_size, num_slots,
     #                             dtype) -> one-layer serving-state leaves
     decode_paged: Callable     # (p, h, positions, cfg, state, tables, *,
-    #                             block_size, window, slot_mask, kernels)
-    #                             -> (y, state)
+    #                             [layer,] block_size, window, slot_mask,
+    #                             kernels) -> (y, state)
     prefill_paged: Callable    # (p, h, starts, limits, slots, cfg, state,
-    #                             tables, *, block_size, window, kernels)
-    #                             -> (y, state)
+    #                             tables, *, [layer,] block_size, window,
+    #                             kernels) -> (y, state)
     #   batched: h (P, C, D); starts/limits/slots (P,) traced vectors;
     #   tables (P, W) — all scheduled prompt chunks in ONE call, filler
     #   rows padded to limit 0 / the null slot
@@ -78,10 +78,46 @@ class MixerSpec:
     # table walk to fuse, MLA prefill needs in-kernel decompression
     # (deferred)
     fused_hooks: Tuple[str, ...] = ()
+    # True: the serving hooks take EVERY layer's stacked leaves (L, ...)
+    # plus a traced ``layer`` index and update that layer in place (paged
+    # attention).  False: they take one layer's leaves, which
+    # decode_layer / prefill_layer slice out and write back.
+    in_place: bool = False
 
     def window(self, cfg) -> Optional[int]:
         """Static sliding window this mixer serves under (None = unbounded)."""
         return cfg.sliding_window if self.state == WINDOWED else None
+
+    def decode_layer(self, p, h, positions, cfg, state, layer, tables, **kw):
+        """``decode_paged`` on layer ``layer`` of the stacked ``state``."""
+        if self.in_place:
+            return self.decode_paged(p, h, positions, cfg, state, tables,
+                                     layer=layer, **kw)
+        y, new = self.decode_paged(p, h, positions, cfg,
+                                   _layer_of(state, layer), tables, **kw)
+        return y, _put_layer(state, new, layer)
+
+    def prefill_layer(self, p, h, starts, limits, slots, cfg, state, layer,
+                      tables, **kw):
+        """``prefill_paged`` on layer ``layer`` of the stacked ``state``."""
+        if self.in_place:
+            return self.prefill_paged(p, h, starts, limits, slots, cfg,
+                                      state, tables, layer=layer, **kw)
+        y, new = self.prefill_paged(p, h, starts, limits, slots, cfg,
+                                    _layer_of(state, layer), tables, **kw)
+        return y, _put_layer(state, new, layer)
+
+
+def _layer_of(state, layer):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        state)
+
+
+def _put_layer(state, new, layer):
+    return jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+            a, n.astype(a.dtype), layer, 0), state, new)
 
 
 _REGISTRY: dict = {}
@@ -249,8 +285,10 @@ def _attn_forward(p, h, positions, cfg, *, window, want_cache):
 
 
 def _attn_init_state(cfg, *, num_blocks, block_size, num_slots, dtype):
+    # lane-dense pages: a page row holds every KV head side by side, so
+    # (KV=2, hd=64) fills the 128 lanes of a TPU tile instead of half
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    shape = (num_blocks, block_size, kv, hd)
+    shape = (num_blocks, block_size, kv * hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -274,18 +312,21 @@ def _gate_slot_update(result, old_state, slot_mask):
     return y, jax.tree.map(sel, new_state, old_state)
 
 
-def _attn_decode_paged(p, h, positions, cfg, state, tables, *, block_size,
-                       window, slot_mask=None, kernels="composed"):
+def _attn_decode_paged(p, h, positions, cfg, state, tables, *, layer,
+                       block_size, window, slot_mask=None,
+                       kernels="composed"):
     return attention.attn_decode_paged(p["attn"], h, positions, cfg, state,
-                                       tables, block_size=block_size,
-                                       window=window, kernels=kernels)
+                                       tables, layer=layer,
+                                       block_size=block_size, window=window,
+                                       kernels=kernels)
 
 
 def _attn_prefill_paged(p, h, starts, limits, slots, cfg, state, tables, *,
-                        block_size, window, kernels="composed"):
+                        layer, block_size, window, kernels="composed"):
     return attention.attn_prefill_paged(p["attn"], h, starts, limits, cfg,
-                                        state, tables, block_size=block_size,
-                                        window=window, kernels=kernels)
+                                        state, tables, layer=layer,
+                                        block_size=block_size, window=window,
+                                        kernels=kernels)
 
 
 for _kind, _state in ((ATTN, PAGED), (LOCAL_ATTN, WINDOWED)):
@@ -302,6 +343,7 @@ for _kind, _state in ((ATTN, PAGED), (LOCAL_ATTN, WINDOWED)):
         decode_paged=_attn_decode_paged,
         prefill_paged=_attn_prefill_paged,
         fused_hooks=("decode", "prefill"),
+        in_place=True,
     ))
 
 

@@ -231,36 +231,52 @@ def decode_step_paged(params, tokens, positions, cfg, kv_pools, block_tables,
     holds a RUNNING request — inactive seats' dummy decode must not
     advance slot-state recurrences (paged writes are naturally routed to
     the null block).  Returns (logits (B, 1, V_pad), new pools).
+
+    The layer scan carries the pools, so each layer updates its pages in
+    the one stacked buffer (:meth:`MixerSpec.decode_layer`).
     """
     x = jnp.take(params["embed"], tokens, axis=0)
     x = constrain(x, ("pod", "data"), None, None)
 
-    new_pools = {}
-    for si, seg in enumerate(segments(cfg)):
-        def body(h, xs, _seg=seg):
-            layer_params, layer_kv = xs
-            new_kv = []
-            for sub_p, kd, kv in zip(layer_params, _seg.kinds, layer_kv):
-                spec = MX.get_mixer(kd[0])
-                y, kv2 = spec.decode_paged(
-                    sub_p, rms_norm(h, sub_p["norm1"], cfg.norm_eps),
-                    positions, cfg, kv, block_tables, block_size=block_size,
-                    window=spec.window(cfg), slot_mask=slot_mask,
-                    kernels=kernels)
-                h = h + y
-                h = _paged_ffn(sub_p, h, cfg, kd[1], moe_dispatch)
-                new_kv.append(kv2)
-            return h, tuple(new_kv)
+    def sublayer(sub_p, kd, h, kv, layer):
+        spec = MX.get_mixer(kd[0])
+        y, kv = spec.decode_layer(
+            sub_p, rms_norm(h, sub_p["norm1"], cfg.norm_eps), positions,
+            cfg, kv, layer, block_tables, block_size=block_size,
+            window=spec.window(cfg), slot_mask=slot_mask, kernels=kernels)
+        return _paged_ffn(sub_p, h + y, cfg, kd[1], moe_dispatch), kv
 
-        x, seg_kv = jax.lax.scan(body, x, (params[f"seg{si}"],
-                                           kv_pools[f"seg{si}"]))
-        new_pools[f"seg{si}"] = seg_kv
-
+    x, new_pools = _paged_layers(params, x, cfg, kv_pools, sublayer)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     unembed = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = x @ unembed.T
     logits = constrain(logits, ("pod", "data"), None, "model")
     return logits, new_pools
+
+
+def _paged_layers(params, x, cfg, pools, sublayer):
+    """Every segment's layer scan over the paged serving state.
+
+    The scan's ``xs`` are the layer params and the layer index; the pool
+    leaves ride in the carry, so the stacked buffers are updated in place
+    and never sliced into ``xs`` / restacked from ``ys``.
+    ``sublayer(sub_p, kind, h, state, layer) -> (h, state)``.
+    """
+    new_pools = {}
+    for si, seg in enumerate(segments(cfg)):
+        def body(carry, xs, _seg=seg):
+            h, seg_pools = carry
+            layer_params, layer = xs
+            new_kv = []
+            for sub_p, kd, kv in zip(layer_params, _seg.kinds, seg_pools):
+                h, kv = sublayer(sub_p, kd, h, kv, layer)
+                new_kv.append(kv)
+            return (h, tuple(new_kv)), None
+
+        (x, new_pools[f"seg{si}"]), _ = jax.lax.scan(
+            body, (x, pools[f"seg{si}"]),
+            (params[f"seg{si}"], jnp.arange(seg.repeat, dtype=jnp.int32)))
+    return x, new_pools
 
 
 def prefill_chunk_paged(params, tokens, starts, limits, slots, cfg, kv_pools,
@@ -287,27 +303,15 @@ def prefill_chunk_paged(params, tokens, starts, limits, slots, cfg, kv_pools,
     P, C = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
 
-    new_pools = {}
-    for si, seg in enumerate(segments(cfg)):
-        def body(h, xs, _seg=seg):
-            layer_params, layer_kv = xs
-            new_kv = []
-            for sub_p, kd, kv in zip(layer_params, _seg.kinds, layer_kv):
-                spec = MX.get_mixer(kd[0])
-                y, kv2 = spec.prefill_paged(
-                    sub_p, rms_norm(h, sub_p["norm1"], cfg.norm_eps),
-                    starts, limits, slots, cfg, kv, block_tables,
-                    block_size=block_size, window=spec.window(cfg),
-                    kernels=kernels)
-                h = h + y
-                h = _paged_ffn(sub_p, h, cfg, kd[1], moe_dispatch)
-                new_kv.append(kv2)
-            return h, tuple(new_kv)
+    def sublayer(sub_p, kd, h, kv, layer):
+        spec = MX.get_mixer(kd[0])
+        y, kv = spec.prefill_layer(
+            sub_p, rms_norm(h, sub_p["norm1"], cfg.norm_eps), starts,
+            limits, slots, cfg, kv, layer, block_tables,
+            block_size=block_size, window=spec.window(cfg), kernels=kernels)
+        return _paged_ffn(sub_p, h + y, cfg, kd[1], moe_dispatch), kv
 
-        x, seg_kv = jax.lax.scan(body, x, (params[f"seg{si}"],
-                                           kv_pools[f"seg{si}"]))
-        new_pools[f"seg{si}"] = seg_kv
-
+    x, new_pools = _paged_layers(params, x, cfg, kv_pools, sublayer)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     # row r's last in-chunk prompt token sits at chunk index
     # min(limit, start + C) - 1 - start (clamped for filler rows)

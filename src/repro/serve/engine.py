@@ -175,7 +175,7 @@ def check_data_axis_serving(axis_sizes) -> None:
             "(ROADMAP: data>1 serving miscompile).  Serve on a flat "
             "(1, n_devices) model-only mesh of the same devices instead "
             "(repro.rl.session.serving_mesh_for does exactly this).")
-def make_pool_shardings(mesh: Optional[Mesh], pool_tree, plan):
+def make_pool_shardings(mesh: Optional[Mesh], pool_tree, plan, cfg):
     """NamedShardings for StatePool leaves (paged pools + per-slot state).
 
     The per-leaf derivation lives in :func:`repro.core.hypershard.
@@ -189,7 +189,8 @@ def make_pool_shardings(mesh: Optional[Mesh], pool_tree, plan):
     layout = layout_for_mesh(mesh)
     paths, leaves, treedef = hypershard.tree_paths(pool_tree)
     shardings = [
-        hypershard.derive_pool(p, tuple(l.shape), layout, plan)[0]
+        hypershard.derive_pool(p, tuple(l.shape), layout, plan,
+                               kv_heads=cfg.num_kv_heads)[0]
         .named_sharding(mesh)
         for p, l in zip(paths, leaves)]
     return jax.tree_util.tree_unflatten(treedef, shardings)
@@ -226,7 +227,7 @@ def make_paged_serve_step(cfg, mesh: Optional[Mesh], plan, *,
         return jax.jit(paged_decode_step, **donate_kw), {}
     pshapes = jax.eval_shape(lambda: M.init_model(cfg, jax.random.PRNGKey(0)))
     param_sh = hypershard.make_param_shardings(mesh, pshapes, plan)
-    pool_sh = make_pool_shardings(mesh, pool_tree, plan)
+    pool_sh = make_pool_shardings(mesh, pool_tree, plan, cfg)
     rep = NamedSharding(mesh, P())
     tok_sh = NamedSharding(mesh, P(None, None))
     tab_sh = NamedSharding(mesh, P(None, None))
@@ -273,7 +274,7 @@ def make_paged_prefill_step(cfg, mesh: Optional[Mesh], plan, *,
         return jax.jit(paged_prefill_step, **donate_kw), {}
     pshapes = jax.eval_shape(lambda: M.init_model(cfg, jax.random.PRNGKey(0)))
     param_sh = hypershard.make_param_shardings(mesh, pshapes, plan)
-    pool_sh = make_pool_shardings(mesh, pool_tree, plan)
+    pool_sh = make_pool_shardings(mesh, pool_tree, plan, cfg)
     rep = NamedSharding(mesh, P())
     tok_sh = NamedSharding(mesh, P(None, None))
     tab_sh = NamedSharding(mesh, P(None, None))

@@ -207,12 +207,15 @@ class StatePool:
       - **paged** sublayers (ATTN, MLA, LOCAL_ATTN): leaves
         ``(L, N_blocks, block, ...)`` — the per-request sequence dim is
         replaced by the shared (block, offset) pool that block tables
-        index;
+        index; attention K/V pages are lane-dense, ``(..., KV*hd)``;
       - **slot** sublayers (SSD, RG-LRU): leaves ``(L, num_slots, ...)``
         — O(1) dense recurrent state, one row per decode seat.
 
-    The leading stacked-layer axis is what the model's ``lax.scan``
-    slices.  Construction resolves the config against the registry
+    The leading stacked-layer axis is what the model's layer scan
+    indexes: paged attention leaves are updated in place, every other
+    leaf is sliced per layer and written back
+    (:meth:`repro.models.mixers.MixerSpec.decode_layer`).  Construction
+    resolves the config against the registry
     (:func:`repro.models.mixers.model_state_layout`) — an unregistered
     mixer kind raises a typed ``ServePlanError`` here, before any jit.
     """
@@ -253,6 +256,16 @@ class StatePool:
     def hbm_bytes(self) -> int:
         return sum(int(a.size) * a.dtype.itemsize
                    for a in jax.tree.leaves(self.state))
+
+    def leaf_counts(self) -> Tuple[int, int]:
+        """``(in place, sliced)``: how many leaves the step programs update
+        in place and how many they slice per layer and write back."""
+        counts = [0, 0]
+        for seg in self.layout.segments:
+            for j, spec in enumerate(seg.specs):
+                n = len(jax.tree.leaves(self.state[seg.name][j]))
+                counts[0 if spec.in_place else 1] += n
+        return counts[0], counts[1]
 
     # -- structural helpers ------------------------------------------------
     # Every pool operation below targets one side of the paged/slot split;
@@ -340,7 +353,8 @@ class StatePool:
             if pad:
                 src = jnp.pad(src, ((0, 0), (0, pad))
                               + ((0, 0),) * (src.ndim - 2))
-            src = src.reshape(src.shape[0], n, bs, *src.shape[2:])
+            # (L, S, KV, hd) caches land in lane-dense (..., KV*hd) pages
+            src = src.reshape((src.shape[0], n, bs) + pool.shape[3:])
             return pool.at[:, idx].set(src.astype(pool.dtype))
 
         self._rewrite(False, lambda sub, j, name: jax.tree.map(

@@ -115,7 +115,8 @@ class ServeEngine:
         if prefill_group is not None:
             from repro.models import mixers as MX
             MX.check_disagg_supported(cfg, self.layout)
-        pool_sh = E.make_pool_shardings(self.mesh, self.pool.state, self.plan)
+        pool_sh = E.make_pool_shardings(self.mesh, self.pool.state, self.plan,
+                                        cfg)
         if pool_sh is not None:
             self.pool.state = jax.tree.map(jax.device_put, self.pool.state,
                                            pool_sh)
@@ -149,6 +150,11 @@ class ServeEngine:
             cfg, self.mesh, self.plan, block_size=scfg.block_size,
             pool_tree=self.pool.state, donate=True, moe_dispatch=moe_dispatch,
             kernels=self.kernel_path)
+        # how the step programs hold the pool: leaves updated in place in
+        # the stacked buffer vs sliced per layer and written back
+        in_place, sliced = self.pool.leaf_counts()
+        self.obs.metrics.gauge("serve.pool.leaves_in_place").set(in_place)
+        self.obs.metrics.gauge("serve.pool.leaves_sliced").set(sliced)
         if prefill_group is None:
             # ONE batched step services every chunk the scheduler admits
             # per iteration (rows padded to the null slot) — a single jit
@@ -707,6 +713,7 @@ class ServeEngine:
         itl = m.histogram("serve.itl_s")
         qw = m.histogram("serve.queue_wait_s")
         s = self.scheduler.stats()
+        in_place, sliced = self.pool.leaf_counts()
         s.update({
             "queue_depth": len(self.scheduler.queue),
             "tokens_generated": self.tokens_generated,
@@ -716,6 +723,8 @@ class ServeEngine:
             "prefill_calls": self.prefill_calls,
             "prefill_chunks": self.prefill_chunks,
             "pool_hbm_bytes": self.pool.hbm_bytes(),
+            "pool_leaves_in_place": in_place,
+            "pool_leaves_sliced": sliced,
             # per-tier archive accounting (HyperMem): host DRAM vs the
             # disk tier the bounded archive spills into, plus how often
             # predictive restore had the state moving before it was seated

@@ -295,6 +295,28 @@ def test_stats_percentiles_and_interval_rate(qwen_f32):
     assert st3["tokens_per_sec"] > 0
 
 
+def test_pool_gauges_set_once_at_build(qwen_f32):
+    """``serve.pool.leaves_in_place`` / ``.leaves_sliced`` count how the
+    step programs hold the pool (qwen2: k and v in place, nothing
+    sliced), set when the engine builds its steps, before any request,
+    and shown in ``stats()`` and the Prometheus dump."""
+    cfg, params = qwen_f32
+    scfg = ServeConfig(block_size=4, num_blocks=16, max_blocks_per_req=4,
+                       max_slots=2, prefill_chunk=4)
+    serve = HyperServe(cfg, params, serve_cfg=scfg)
+    m = serve.obs().metrics
+    assert m.gauge("serve.pool.leaves_in_place").value == 2
+    assert m.gauge("serve.pool.leaves_sliced").value == 0
+    serve.submit(list(range(1, 6)), 3)
+    serve.join()
+    st = serve.stats()
+    assert (st["pool_leaves_in_place"], st["pool_leaves_sliced"]) == (2, 0)
+    text = m.dump_prometheus()
+    assert "# TYPE serve_pool_leaves_in_place gauge\n" \
+        "serve_pool_leaves_in_place 2" in text
+    assert "serve_pool_leaves_sliced 0" in text
+
+
 def test_stream_final_meta_lifecycle_record(qwen_f32):
     cfg, params = qwen_f32
     scfg = ServeConfig(block_size=4, num_blocks=40, max_blocks_per_req=8,

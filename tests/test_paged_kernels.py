@@ -28,9 +28,10 @@ BS, W, N = 4, 6, 32                     # block size, table width, pool blocks
 
 
 def _pools(key, kv_heads, head_dim):
+    # lane-dense pages: every KV head of a page row side by side
     kk, kv = jax.random.split(key)
-    k_pool = jax.random.normal(kk, (N, BS, kv_heads, head_dim)) * 0.3
-    v_pool = jax.random.normal(kv, (N, BS, kv_heads, head_dim)) * 0.3
+    k_pool = jax.random.normal(kk, (N, BS, kv_heads * head_dim)) * 0.3
+    v_pool = jax.random.normal(kv, (N, BS, kv_heads * head_dim)) * 0.3
     return k_pool, v_pool
 
 

@@ -199,6 +199,84 @@ def test_pool_hbm_accounting():
     assert pool.hbm_bytes() == want
 
 
+def _ssd_attn_hybrid():
+    """SSD and full attention alternating in one segment."""
+    rg = get_config("recurrentgemma-2b").reduced()
+    return dataclasses.replace(
+        rg, dtype="float32", num_layers=4,
+        ssm=get_config("mamba2-370m").reduced().ssm,
+        rglru=dataclasses.replace(rg.rglru, block_pattern=("ssd", "attn")))
+
+
+@pytest.mark.parametrize("arch,want", [
+    ("qwen2-0.5b", (2, 0)),               # k, v in place
+    ("granite-3-2b", (2, 0)),
+    ("deepseek-v2-lite-16b", (0, 4)),     # ckv, krope x 2 segments, sliced
+    ("ssd-attn", (2, 2)),                 # k, v in place; state, conv sliced
+])
+def test_pool_leaves_in_place_or_sliced(arch, want):
+    """Attention pages are lane-dense, (L, N, bs, KV*hd), and updated in
+    place; every other leaf is sliced per layer and written back."""
+    cfg = (_ssd_attn_hybrid() if arch == "ssd-attn"
+           else get_config(arch).reduced())
+    pcfg = PagedKVConfig(block_size=4, num_blocks=16)
+    pool = PagedKVPool(cfg, pcfg, num_slots=2)
+    assert pool.leaf_counts() == want
+    for seg in pool.layout.segments:
+        for j, spec in enumerate(seg.specs):
+            if spec.in_place:
+                for a in pool.state[seg.name][j].values():
+                    assert a.shape == (seg.repeat, 16, 4, cfg.num_kv_heads
+                                       * cfg.resolved_head_dim)
+
+
+def test_seat_prefill_caches_lands_heads_side_by_side():
+    """A dense (L, B, S, KV, hd) prefill cache seats into lane-dense
+    pages: position p of the request is row p % bs of its table's page
+    p // bs, every KV head side by side."""
+    cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(),
+                              dtype="float32")
+    pcfg = PagedKVConfig(block_size=4, num_blocks=16, dtype="float32")
+    pool = PagedKVPool(cfg, pcfg, dtype=jnp.float32)
+    L, KV, hd, S = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim, 7
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    dense = {n: jax.random.normal(k, (L, 2, S, KV, hd))
+             for n, k in zip("kv", ks)}
+    pool.seat_prefill_caches({"seg0": (dense,)}, [5, 9], S, row=1)
+    for n in "kv":
+        a = pool.state["seg0"][0][n]
+        for p in range(S):
+            got = a[:, [5, 9][p // 4], p % 4]
+            assert (got == dense[n][:, 1, p].reshape(L, KV * hd)).all()
+        assert (a[:, 9, 3] == 0).all()           # past the prompt: untouched
+
+
+@pytest.mark.parametrize("kernels", ["composed", "fused"])
+def test_layer_offset_writes_each_layers_own_pages(kernels):
+    """One decode step over two stacked attention layers: layer l's token
+    lands in page ``table[pos // bs]`` of layer l, an empty seat's dummy
+    token in layer l's own null block 0, and no other page changes."""
+    from repro.models import model as M
+    cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(),
+                              dtype="float32")
+    assert cfg.num_layers == 2
+    params = M.init_model(cfg, jax.random.PRNGKey(0))
+    pcfg = PagedKVConfig(block_size=4, num_blocks=12, dtype="float32")
+    pool = PagedKVPool(cfg, pcfg, dtype=jnp.float32)
+    tables = jnp.asarray([[3, 7, 0], [0, 0, 0]], jnp.int32)
+    _, new = M.decode_step_paged(
+        params, jnp.asarray([[11], [0]], jnp.int32),
+        jnp.asarray([5, 0], jnp.int32), cfg, pool.state, tables,
+        block_size=4, slot_mask=jnp.asarray([True, False]), kernels=kernels)
+    for n in "kv":
+        a = new["seg0"][0][n]                    # (L, N, bs, KV*hd)
+        written = jnp.abs(a).sum(-1) > 0         # (L, N, bs)
+        want = jnp.zeros_like(written).at[:, 7, 1].set(True) \
+            .at[:, 0, 0].set(True)
+        assert (written == want).all(), n
+        assert not jnp.allclose(a[0, 7, 1], a[1, 7, 1])   # two layers
+
+
 # ---------------------------------------------------------------------------
 # BlockManager invariants under random op sequences (mini-hypothesis)
 # ---------------------------------------------------------------------------

@@ -12,7 +12,9 @@ runs, so numbers are checked elsewhere (``tests/test_kernels.py``,
 The topology is described inside a fixture, never at import: only one
 process may hold libtpu, and every test worker imports this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,9 +23,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
 from repro.kernels import (decode_attention as da, flash_attention as fa,
-                           grouped_matmul as gm, paged_decode_attention as pda,
+                           grouped_matmul as gm, ops,
+                           paged_decode_attention as pda,
                            ragged_prefill_attention as rpa, rglru_scan as rs,
                            ssd_scan as ss)
+from repro.models import model as M
+from repro.serve import engine as E
+from repro.serve.paged_kv import PagedKVConfig, StatePool
 
 QWEN = get_config("qwen2-0.5b")
 H, KV, D = QWEN.num_heads, QWEN.num_kv_heads, QWEN.resolved_head_dim
@@ -67,8 +73,8 @@ def test_paged_decode_compiles(one_chip, window):
     _compile(one_chip,
              lambda q, k, v, t, n: pda.paged_decode_attention(
                  q, k, v, t, n, block_size=BS, window=window),
-             ((B, 1, H, D), BF), ((N, BS, KV, D), BF), ((N, BS, KV, D), BF),
-             ((B, W), I32), ((B,), I32))
+             ((B, 1, H, D), BF), ((N, BS, KV * D), BF),
+             ((N, BS, KV * D), BF), ((B, W), I32), ((B,), I32))
 
 
 @pytest.mark.parametrize("rows,chunk", [(4, 128), (1, 32), (2, 24)])
@@ -76,9 +82,69 @@ def test_ragged_prefill_compiles(one_chip, rows, chunk):
     _compile(one_chip,
              lambda q, k, v, t, s, n: rpa.ragged_prefill_attention(
                  q, k, v, t, s, n, block_size=BS),
-             ((rows, chunk, H, D), BF), ((N, BS, KV, D), BF),
-             ((N, BS, KV, D), BF), ((rows, W), I32), ((rows,), I32),
+             ((rows, chunk, H, D), BF), ((N, BS, KV * D), BF),
+             ((N, BS, KV * D), BF), ((rows, W), I32), ((rows,), I32),
              ((rows,), I32))
+
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([a-z][\w\-]*)\(")
+# ops that only pass a buffer along: no bytes of the pool move
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def _page_axis_ops(hlo, sizes):
+    """``(opcode, line)`` of every instruction whose result has a dim in
+    ``sizes`` (the pool's page axis, alone or merged with its layers)."""
+    hits = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m and any(sizes & {int(d) for d in dims.split(",") if d}
+                     for dims in re.findall(r"\[([\d,]*)\]", m.group(1))):
+            hits.append((m.group(2), line.strip()))
+    return hits
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_steps_update_pool_in_place(one_chip, program, monkeypatch):
+    """The real paged step programs, qwen2-0.5b cut to 2 layers over an
+    8,193-page pool (the chat cell's; like any served pool, too large for
+    VMEM): the only op over the pool that moves bytes is the token
+    scatter, in place (``aliasing_operands``).  No copy, slice or
+    dynamic-update-slice of a layer or of the whole pool, in the layer
+    loop or around it; the kernels read the scattered pool directly."""
+    monkeypatch.setattr(ops, "_MODE", "pallas")   # the chip's lowering
+    cfg = dataclasses.replace(QWEN, num_layers=2)
+    pages = 8193
+    pcfg = PagedKVConfig(block_size=BS, num_blocks=pages,
+                         max_blocks_per_req=W, dtype="bfloat16")
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    arg = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt,
+                                                      sharding=one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_model(cfg, jax.random.PRNGKey(0))))
+    pool = on_chip(jax.eval_shape(
+        lambda: StatePool(cfg, pcfg, num_slots=B).state))
+    if program == "decode":
+        step, _ = E.make_paged_serve_step(cfg, None, None, block_size=BS,
+                                          kernels="fused")
+        args = (params, arg((B, 1)), arg((B,)), pool, arg((B, W)),
+                arg((B,), jnp.bool_))
+    else:
+        step, _ = E.make_paged_prefill_step(cfg, None, None, block_size=BS,
+                                            kernels="fused")
+        args = (params, arg((4, 128)), arg((4,)), arg((4,)), arg((4,)),
+                pool, arg((4, W)))
+    hlo = step.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    moved = [(op, line) for op, line in
+             _page_axis_ops(hlo, {pages, cfg.num_layers * pages})
+             if op not in _PLUMBING]
+    fusions = [line for op, line in moved if op == "fusion"]
+    assert fusions and all("aliasing_operands" in f for f in fusions), \
+        fusions
+    assert {op for op, _ in moved} <= {"fusion", "scatter"}, moved
 
 
 @pytest.mark.parametrize("cache,window", [(544, None), (544, 128),
