@@ -40,6 +40,33 @@ class MoEConfig:
     router_aux_coef: float = 0.001
     router_z_coef: float = 1e-4
     first_k_dense: int = 1           # leading layers that use a dense FFN
+    # the gate: top-k of the softmax over every routed expert, renormalised
+    # to sum to 1 where ``norm_topk_prob`` holds, then scaled
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the expert share: this device holds routed experts
+    # [first_expert_held, first_expert_held + experts_held) of each layer
+    # (0 => all of them); the router still scores all ``num_experts`` and
+    # routes to absent experts add nothing here
+    experts_held: int = 0
+    first_expert_held: int = 0
+
+    def __post_init__(self):
+        if (self.experts_held < 0 or self.first_expert_held < 0
+                or self.first_expert_held + self.held > self.num_experts):
+            raise ValueError(
+                f"expert share [{self.first_expert_held}, "
+                f"{self.first_expert_held + self.held}) lies outside the "
+                f"{self.num_experts} routed experts")
+
+    @property
+    def held(self) -> int:
+        """Routed experts this device holds per layer."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def is_share(self) -> bool:
+        return self.held < self.num_experts
 
 
 @dataclass(frozen=True)
@@ -49,6 +76,17 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 publishes it."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -90,6 +128,7 @@ class ModelConfig:
     tie_embeddings: bool = False
     sliding_window: int = 4096       # used by LOCAL_ATTN blocks
     long_context_window: int = 8192  # sliding-window cache used for long_500k
+    rope_scaling: Optional[RopeScaling] = None   # None => plain rope
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -204,7 +243,8 @@ class ModelConfig:
         if self.moe is not None:
             kw["moe"] = replace(self.moe, num_experts=4, num_shared_experts=1,
                                 top_k=2, d_ff_expert=min(self.moe.d_ff_expert, d),
-                                first_k_dense=1)
+                                first_k_dense=1, experts_held=0,
+                                first_expert_held=0)
         if self.mla is not None:
             kw["mla"] = replace(self.mla, kv_lora_rank=64, qk_nope_head_dim=hd,
                                 qk_rope_head_dim=hd // 2, v_head_dim=hd)

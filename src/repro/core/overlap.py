@@ -71,33 +71,37 @@ def overlap_efficiency(compute_s: float, comm_s: float, chunks: int,
 # ---------------------------------------------------------------------------
 # ragged (sort-based) MoE dispatch — beyond-paper optimized path
 # ---------------------------------------------------------------------------
-def ragged_moe_apply(p, xf, idx, gate_vals, cfg):
+def ragged_moe_apply(p, xf, local, gate_vals, cfg):
     """Per-shard sort-based expert application (no capacity one-hot).
 
-    xf: (T, D); idx: (T, k); gate_vals: (T, k).  Computes the routed-expert
-    sum via sort -> ragged grouped matmul -> unsort.  Under shard_map with
-    experts sharded this composes with an all-to-all; under plain pjit it
-    is a dense-semantics fallback that XLA partitions.
+    xf: (T, D); local: (T, k) each route's held expert in ``[0, held)``,
+    or ``held`` for a route this device does not compute
+    (:func:`repro.models.moe.moe_forward`); gate_vals: (T, k).  Computes
+    the held experts' routed sum via sort -> ragged grouped matmul ->
+    unsort: the other routes sort past the held groups, which
+    ``group_sizes`` does not cover, so the kernel skips their rows.  Under
+    shard_map with experts sharded this composes with an all-to-all;
+    under plain pjit it is a dense-semantics fallback that XLA partitions.
     """
     from repro.kernels import ops
-    mo = cfg.moe
     T, D = xf.shape
-    E, k = mo.num_experts, mo.top_k
+    held, k = cfg.moe.held, local.shape[-1]
 
-    flat_expert = idx.reshape(-1)                       # (T*k,)
+    flat_expert = local.reshape(-1)                     # (T*k,)
     flat_tok = jnp.repeat(jnp.arange(T), k)
     order = jnp.argsort(flat_expert)
-    sorted_expert = flat_expert[order]
     sorted_tok = flat_tok[order]
     xs = xf[sorted_tok]                                 # (T*k, D)
 
-    group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+    group_sizes = jnp.bincount(flat_expert, length=held + 1)[:held].astype(
+        jnp.int32)
 
     h = ops.grouped_matmul(xs, p["w_gate"], group_sizes)
     h = jax.nn.silu(h) * ops.grouped_matmul(xs, p["w_up"], group_sizes)
     out = ops.grouped_matmul(h, p["w_down"], group_sizes)   # (T*k, D)
 
-    gates = gate_vals.reshape(-1)[order].astype(out.dtype)
+    gates = jnp.where(flat_expert < held, gate_vals.reshape(-1), 0)
+    gates = gates[order].astype(out.dtype)
     y = jnp.zeros((T, D), out.dtype).at[sorted_tok].add(out * gates[:, None])
     return y
 
@@ -115,8 +119,9 @@ def ep_moe_shardmap(p, x, cfg, mesh: Mesh, *, ep_axis: str = "model",
     Chunking the a2a lets transfer k+1 overlap expert-matmul k (paper's
     90% masking mechanism, explicit).
     """
-    from repro.models.moe import router_probs
+    from repro.models.moe import _refuse_share, gate, router_probs
     mo = cfg.moe
+    _refuse_share(mo, "ep_shardmap")
     E = mo.num_experts
     n_ep = mesh.shape[ep_axis]
     e_local = E // n_ep
@@ -126,8 +131,7 @@ def ep_moe_shardmap(p, x, cfg, mesh: Mesh, *, ep_axis: str = "model",
         T = B * S
         xf = xx.reshape(T, D)
         probs, _ = router_probs(px, xf, cfg)
-        gate_vals, idx = jax.lax.top_k(probs, mo.top_k)
-        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+        gate_vals, idx = gate(probs, mo)
 
         # capacity per (src shard, dst shard): fixed so a2a is static-shaped
         cap = max(1, int(T * mo.top_k / E * mo.capacity_factor) * e_local)

@@ -257,16 +257,19 @@ def paged_mla_decode_attention(
 def grouped_matmul(
     x: jax.Array,            # (T, D) tokens sorted by expert
     w: jax.Array,            # (E, D, F)
-    group_sizes: jax.Array,  # (E,) int32, sum == T
+    group_sizes: jax.Array,  # (E,) int32, sum <= T
 ) -> jax.Array:
-    """Ragged grouped matmul oracle: out[t] = x[t] @ w[expert_of(t)]."""
+    """Ragged grouped matmul oracle: out[t] = x[t] @ w[expert_of(t)], and
+    0 for the rows past every group (as the kernel leaves them)."""
     T, D = x.shape
     E, _, F = w.shape
     bounds = jnp.cumsum(group_sizes)
-    expert_of = jnp.searchsorted(bounds, jnp.arange(T), side="right")
-    wt = w[expert_of]                                           # (T, D, F)
-    return jnp.einsum("td,tdf->tf", x.astype(jnp.float32),
-                      wt.astype(jnp.float32)).astype(x.dtype)
+    rows = jnp.arange(T)
+    expert_of = jnp.searchsorted(bounds, rows, side="right")
+    wt = w[jnp.minimum(expert_of, E - 1)]                       # (T, D, F)
+    out = jnp.einsum("td,tdf->tf", x.astype(jnp.float32),
+                     wt.astype(jnp.float32))
+    return jnp.where((rows < bounds[-1])[:, None], out, 0).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

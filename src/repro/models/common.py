@@ -6,6 +6,8 @@ be attached by tree path).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,21 +48,57 @@ def swiglu(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> np.ndarray:
+    """Inverse frequencies of the rotary pairs: ``theta**(-2i/d)``, or with
+    ``scaling`` (a :class:`~repro.configs.base.RopeScaling`) YaRN's blend
+    of those and of the same divided by the factor, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes it."""
     half = head_dim // 2
-    return 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    extra = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    if scaling is None:
+        return extra
+    inter = extra / scaling.factor
+
+    def dim_of(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0, 1)
+    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D) with D even; positions: (S,) or (B, S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_magnitude(scaling) -> float:
+    """What YaRN multiplies cos and sin by (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
+    """x: (B, S, H, D) with D even; positions: (S,) or (B, S).  Pairs are
+    the two halves of D (half-split rotation); ``scaling``: YaRN or None."""
     D = x.shape[-1]
-    inv = jnp.asarray(rope_freqs(D, theta))                    # (D/2,)
+    inv = jnp.asarray(rope_freqs(D, theta, scaling))           # (D/2,)
     if positions.ndim == 1:
         ang = positions[None, :, None].astype(jnp.float32) * inv
     else:
         ang = positions[..., None].astype(jnp.float32) * inv   # (B, S, D/2)
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
+    mag = rope_magnitude(scaling)
+    cos = jnp.cos(ang)[:, :, None, :] * mag
+    sin = jnp.sin(ang)[:, :, None, :] * mag
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -11,7 +11,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import apply_rope, dense_init, dtype_of, rms_norm
+from repro.models.common import (apply_rope, dense_init, dtype_of, rms_norm,
+                                 yarn_mscale)
+
+
+def softmax_scale(cfg) -> float:
+    """``(qk_nope + qk_rope)**-0.5``, times YaRN's ``mscale(factor,
+    mscale_all_dim)**2`` where the config scales its rope."""
+    m, rs = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
 
 
 def init_mla(cfg, key):
@@ -38,12 +49,13 @@ def _latents(p, x, positions, cfg):
     H = cfg.num_heads
     q = (x @ p["wq"]).reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     dkv = x @ p["w_dkv"]
     c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
     c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)
     return q_nope, q_rope, c_kv, k_rope[:, :, 0, :]
 
 
@@ -60,7 +72,7 @@ def mla_forward(p, x, positions, cfg, *, window=None, return_cache=False):
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (B, S, H, m.qk_rope_head_dim))], axis=-1)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     from repro.models.attention import full_attention
     out = full_attention(q, k, v, window=window, scale=scale)
     y = out.reshape(B, S, H * m.v_head_dim) @ p["wo"]
@@ -118,7 +130,7 @@ def mla_decode_paged(p, x, positions, cfg, kv, block_tables, *,
 
     w_uk = p["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)       # (B,H,R)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     if kernels == "fused":
         from repro.kernels import ops
         o_lat = ops.paged_mla_decode_attention(
@@ -184,7 +196,7 @@ def mla_prefill_chunk_paged(p, x, starts, limits, cfg, kv, block_tables, *,
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(krope_seq[:, :, None, :],
                                   (P, S, H, m.qk_rope_head_dim))], axis=-1)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     out = flash_rows(q, k, v, starts, scale=scale)
     y = out.reshape(P, C, H * m.v_head_dim) @ p["wo"]
     return y, {"ckv": ckv_pool, "krope": krope_pool}
@@ -211,7 +223,7 @@ def mla_decode(p, x, pos, cfg, cache, *, window=None):
     w_uk = p["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)       # (B,H,R)
 
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = softmax_scale(cfg)
     s = (jnp.einsum("bhr,bsr->bhs", q_lat.astype(jnp.float32),
                     ckv.astype(jnp.float32))
          + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32),

@@ -205,16 +205,20 @@ def init_caches(cfg, batch, cache_len, *, dtype=None, window_override=None):
     return caches
 
 
-def _paged_ffn(p, x, cfg, ffn, moe_dispatch):
+def _paged_ffn(p, x, cfg, ffn, moe_dispatch, valid):
+    """The sublayer's FFN leg: ``(x, routes)``, where ``routes`` is the MoE
+    layer's (held,) count of valid tokens' routes to its held experts, or
+    None for a dense leg."""
     if ffn == DENSE_FFN:
         h = rms_norm(x, p["norm2"], cfg.norm_eps)
         x = x + swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"],
                        p["ffn"]["w_down"])
     elif ffn == MOE_FFN:
         h = rms_norm(x, p["norm2"], cfg.norm_eps)
-        y, _ = moe_mod.moe_forward(p["ffn"], h, cfg, dispatch=moe_dispatch)
-        x = x + y
-    return x
+        y, mm = moe_mod.moe_forward(p["ffn"], h, cfg, dispatch=moe_dispatch,
+                                    valid=valid)
+        return x + y, mm["routes_held"]
+    return x, None
 
 
 def decode_step_paged(params, tokens, positions, cfg, kv_pools, block_tables,
@@ -230,13 +234,16 @@ def decode_step_paged(params, tokens, positions, cfg, kv_pools, block_tables,
     block_tables: (B, W) int32; slot_mask: (B,) bool, True where the seat
     holds a RUNNING request — inactive seats' dummy decode must not
     advance slot-state recurrences (paged writes are naturally routed to
-    the null block).  Returns (logits (B, 1, V_pad), new pools).
+    the null block).  Returns (logits (B, 1, V_pad), new pools), and for
+    a config with MoE layers also their held-expert route counts
+    (n_moe_layers, held) int32 (seats outside ``slot_mask`` route nowhere).
 
     The layer scan carries the pools, so each layer updates its pages in
     the one stacked buffer (:meth:`MixerSpec.decode_layer`).
     """
     x = jnp.take(params["embed"], tokens, axis=0)
     x = constrain(x, ("pod", "data"), None, None)
+    valid = None if slot_mask is None else slot_mask[:, None]
 
     def sublayer(sub_p, kd, h, kv, layer):
         spec = MX.get_mixer(kd[0])
@@ -244,14 +251,15 @@ def decode_step_paged(params, tokens, positions, cfg, kv_pools, block_tables,
             sub_p, rms_norm(h, sub_p["norm1"], cfg.norm_eps), positions,
             cfg, kv, layer, block_tables, block_size=block_size,
             window=spec.window(cfg), slot_mask=slot_mask, kernels=kernels)
-        return _paged_ffn(sub_p, h + y, cfg, kd[1], moe_dispatch), kv
+        h, routes = _paged_ffn(sub_p, h + y, cfg, kd[1], moe_dispatch, valid)
+        return h, kv, routes
 
-    x, new_pools = _paged_layers(params, x, cfg, kv_pools, sublayer)
+    x, new_pools, routes = _paged_layers(params, x, cfg, kv_pools, sublayer)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     unembed = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = x @ unembed.T
     logits = constrain(logits, ("pod", "data"), None, "model")
-    return logits, new_pools
+    return (logits, new_pools) + (() if routes is None else (routes,))
 
 
 def _paged_layers(params, x, cfg, pools, sublayer):
@@ -260,23 +268,30 @@ def _paged_layers(params, x, cfg, pools, sublayer):
     The scan's ``xs`` are the layer params and the layer index; the pool
     leaves ride in the carry, so the stacked buffers are updated in place
     and never sliced into ``xs`` / restacked from ``ys``.
-    ``sublayer(sub_p, kind, h, state, layer) -> (h, state)``.
+    ``sublayer(sub_p, kind, h, state, layer) -> (h, state, routes)``;
+    returns ``(x, new pools, routes)``, the MoE sublayers' route counts
+    stacked in layer order ((n_moe_layers, held), or None without MoE).
     """
-    new_pools = {}
+    new_pools, routes = {}, []
     for si, seg in enumerate(segments(cfg)):
         def body(carry, xs, _seg=seg):
             h, seg_pools = carry
             layer_params, layer = xs
-            new_kv = []
+            new_kv, counts = [], []
             for sub_p, kd, kv in zip(layer_params, _seg.kinds, seg_pools):
-                h, kv = sublayer(sub_p, kd, h, kv, layer)
+                h, kv, c = sublayer(sub_p, kd, h, kv, layer)
                 new_kv.append(kv)
-            return (h, tuple(new_kv)), None
+                if c is not None:
+                    counts.append(c)
+            return (h, tuple(new_kv)), (jnp.stack(counts) if counts
+                                        else None)
 
-        (x, new_pools[f"seg{si}"]), _ = jax.lax.scan(
+        (x, new_pools[f"seg{si}"]), seg_routes = jax.lax.scan(
             body, (x, pools[f"seg{si}"]),
             (params[f"seg{si}"], jnp.arange(seg.repeat, dtype=jnp.int32)))
-    return x, new_pools
+        if seg_routes is not None:                # (repeat, n_moe_sub, held)
+            routes.append(seg_routes.reshape(-1, seg_routes.shape[-1]))
+    return x, new_pools, (jnp.concatenate(routes) if routes else None)
 
 
 def prefill_chunk_paged(params, tokens, starts, limits, slots, cfg, kv_pools,
@@ -298,10 +313,13 @@ def prefill_chunk_paged(params, tokens, starts, limits, slots, cfg, kv_pools,
     last in-chunk prompt token, the only position whose logits any caller
     reads (they seed the first sampled token of rows finishing their
     prompt), so the unembedding matmul — the dominant per-chunk FLOP for
-    real vocabularies — runs over P rows instead of P*C.
+    real vocabularies — runs over P rows instead of P*C.  A config with
+    MoE layers also gets their held-expert route counts (n_moe_layers,
+    held) int32 (padding positions route nowhere).
     """
     P, C = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
+    valid = starts[:, None] + jnp.arange(C)[None, :] < limits[:, None]
 
     def sublayer(sub_p, kd, h, kv, layer):
         spec = MX.get_mixer(kd[0])
@@ -309,9 +327,10 @@ def prefill_chunk_paged(params, tokens, starts, limits, slots, cfg, kv_pools,
             sub_p, rms_norm(h, sub_p["norm1"], cfg.norm_eps), starts,
             limits, slots, cfg, kv, layer, block_tables,
             block_size=block_size, window=spec.window(cfg), kernels=kernels)
-        return _paged_ffn(sub_p, h + y, cfg, kd[1], moe_dispatch), kv
+        h, routes = _paged_ffn(sub_p, h + y, cfg, kd[1], moe_dispatch, valid)
+        return h, kv, routes
 
-    x, new_pools = _paged_layers(params, x, cfg, kv_pools, sublayer)
+    x, new_pools, routes = _paged_layers(params, x, cfg, kv_pools, sublayer)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     # row r's last in-chunk prompt token sits at chunk index
     # min(limit, start + C) - 1 - start (clamped for filler rows)
@@ -320,7 +339,7 @@ def prefill_chunk_paged(params, tokens, starts, limits, slots, cfg, kv_pools,
                                  axis=1)[:, 0]                # (P, D)
     unembed = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = x_last @ unembed.T
-    return logits, new_pools
+    return (logits, new_pools) + (() if routes is None else (routes,))
 
 
 def decode_step(params, token, pos, cfg, caches, *, window_override=None,

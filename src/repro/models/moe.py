@@ -6,6 +6,11 @@ the ``model`` axis (XLA SPMD inserts the all-to-all).  The beyond-paper
 sort-based ragged dispatch lives in :mod:`repro.core.overlap` and
 :mod:`repro.kernels.grouped_matmul` and is selected with
 ``dispatch="ragged"``.
+
+An expert share (``MoEConfig.experts_held`` / ``first_expert_held``) holds
+only those routed experts' weights: the router scores every expert, and
+routes to absent experts add nothing here (expert parallelism without its
+exchange).  Only the ragged dispatch computes a share.
 """
 from __future__ import annotations
 
@@ -23,10 +28,10 @@ def init_moe(cfg, key):
     d, F = cfg.d_model, mo.d_ff_expert
     dt = dtype_of(cfg)
     ks = jax.random.split(key, 7)
-    E = mo.num_experts
+    E = mo.held
     Fs = F * mo.num_shared_experts
     return {
-        "router": dense_init(ks[0], d, E, jnp.float32),
+        "router": dense_init(ks[0], d, mo.num_experts, jnp.float32),
         "w_gate": jax.random.normal(ks[1], (E, d, F), jnp.float32).astype(dt) * (2.0 / (d + F)) ** 0.5,
         "w_up": jax.random.normal(ks[2], (E, d, F), jnp.float32).astype(dt) * (2.0 / (d + F)) ** 0.5,
         "w_down": jax.random.normal(ks[3], (E, F, d), jnp.float32).astype(dt) * (2.0 / (d + F)) ** 0.5,
@@ -49,16 +54,52 @@ def router_probs(p, x, cfg):
     return jax.nn.softmax(logits, axis=-1), logits
 
 
-def moe_forward(p, x, cfg, *, dispatch: str = "gshard"):
-    """x: (B, S, D) -> (y (B, S, D), aux_metrics dict)."""
+def gate(probs, mo):
+    """Top-k of the router's probabilities: (gate values, expert ids), each
+    (T, k); the values renormalised where ``norm_topk_prob`` holds, then
+    times ``routed_scaling_factor``."""
+    gate_vals, idx = jax.lax.top_k(probs, mo.top_k)
+    if mo.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True),
+                                            1e-9)
+    return gate_vals * mo.routed_scaling_factor, idx
+
+
+def _refuse_share(mo, dispatch: str) -> None:
+    if mo.is_share:
+        from repro.api.errors import PlanError
+        raise PlanError(
+            f"moe dispatch {dispatch!r} cannot compute an expert share "
+            f"({mo.held} of {mo.num_experts} routed experts held): only the "
+            "ragged dispatch (serving's default) routes past absent experts")
+
+
+def moe_forward(p, x, cfg, *, dispatch: str = "gshard", valid=None):
+    """x: (B, S, D) -> (y (B, S, D), aux_metrics dict).
+
+    ``valid`` (B, S) bool marks real tokens (serving's padding and empty
+    seats are not): the ragged dispatch sends the others' routes nowhere.
+    ``aux_metrics["routes_held"]`` (held,) int32 counts the valid tokens'
+    routes to each held expert."""
     mo = cfg.moe
     B, S, D = x.shape
     T = B * S
     xf = x.reshape(T, D)
+    vf = None if valid is None else valid.reshape(T)
 
     probs, logits = router_probs(p, xf, cfg)
-    gate_vals, idx = jax.lax.top_k(probs, mo.top_k)             # (T, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, idx = gate(probs, mo)                            # (T, k)
+    # each route's expert as this device holds it, in [0, held), or held
+    # for a route to an absent expert or from a token outside ``valid``
+    local = idx - mo.first_expert_held
+    keep = (local >= 0) & (local < mo.held)
+    if vf is not None:
+        keep = keep & vf[:, None]
+    local = jnp.where(keep, local, mo.held)
+    routes_held = jnp.bincount(local.reshape(-1),
+                               length=mo.held + 1)[:mo.held].astype(jnp.int32)
+    if dispatch != "ragged":
+        _refuse_share(mo, dispatch)
 
     # aux losses (fp32)
     E = mo.num_experts
@@ -71,7 +112,7 @@ def moe_forward(p, x, cfg, *, dispatch: str = "gshard"):
 
     if dispatch == "ragged":
         from repro.core.overlap import ragged_moe_apply
-        y = ragged_moe_apply(p, xf, idx, gate_vals, cfg)
+        y = ragged_moe_apply(p, xf, local, gate_vals, cfg)
     elif dispatch == "dp_local":
         from repro.core.meshctx import current_mesh
         from repro.core.overlap import moe_dp_local, ragged_moe_apply
@@ -85,7 +126,7 @@ def moe_forward(p, x, cfg, *, dispatch: str = "gshard"):
             tpn = mesh.shape.get("model", 1)
             ok = B % dpn == 0 and S % tpn == 0
         if not ok:
-            y = ragged_moe_apply(p, xf, idx, gate_vals, cfg)
+            y = ragged_moe_apply(p, xf, local, gate_vals, cfg)
         else:
             y = moe_dp_local(p, x, idx.reshape(B, S, -1),
                              gate_vals.reshape(B, S, -1), cfg,
@@ -98,7 +139,8 @@ def moe_forward(p, x, cfg, *, dispatch: str = "gshard"):
     y = y + sh
 
     metrics = {"moe_aux_loss": aux_loss, "moe_z_loss": z_loss,
-               "router_entropy": -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-9), -1))}
+               "router_entropy": -jnp.mean(jnp.sum(probs * jnp.log(probs + 1e-9), -1)),
+               "routes_held": routes_held}
     return y.reshape(B, S, D), metrics
 
 

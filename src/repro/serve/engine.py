@@ -205,7 +205,9 @@ def make_paged_serve_step(cfg, mesh: Optional[Mesh], plan, *,
 
     Returns ``step(params, tokens (B,1), positions (B,), pools, tables
     (B,W), slot_mask (B,)) -> (logits, new pools)`` with the pool donated
-    (updated in place on device).  ``slot_mask`` marks the seats holding
+    (updated in place on device); a config with MoE layers also gets the
+    held experts' route counts (n_moe_layers, held) int32.
+    ``slot_mask`` marks the seats holding
     RUNNING requests: inactive seats' dummy decode must not advance
     slot-state recurrences.  The seat count B and table width W are fixed
     by the arrays the caller passes (one compilation per distinct shape).
@@ -235,7 +237,8 @@ def make_paged_serve_step(cfg, mesh: Optional[Mesh], plan, *,
     jitted = jax.jit(paged_decode_step,
                      in_shardings=(param_sh, tok_sh, rep, pool_sh, tab_sh,
                                    rep),
-                     out_shardings=(logits_sh, pool_sh), **donate_kw)
+                     out_shardings=(logits_sh, pool_sh)
+                     + ((rep,) if cfg.moe is not None else ()), **donate_kw)
     return jitted, {"params": param_sh, "pools": pool_sh}
 
 
@@ -246,7 +249,8 @@ def make_paged_prefill_step(cfg, mesh: Optional[Mesh], plan, *,
                             kernels: str = "composed"):
     """Batched chunked-prefill step: ``(params, tokens (P,C), starts (P,),
     limits (P,), slots (P,), pools, tables (P,W)) -> (last_logits (P,V),
-    new pools)``.
+    new pools)``, and for a config with MoE layers the held experts' route
+    counts (n_moe_layers, held) int32.
 
     Every prompt chunk the scheduler admitted this iteration runs in ONE
     compiled call — one kernel launch amortised over all P rows instead
@@ -282,7 +286,8 @@ def make_paged_prefill_step(cfg, mesh: Optional[Mesh], plan, *,
     jitted = jax.jit(paged_prefill_step,
                      in_shardings=(param_sh, tok_sh, rep, rep, rep, pool_sh,
                                    tab_sh),
-                     out_shardings=(out0_sh, pool_sh), **donate_kw)
+                     out_shardings=(out0_sh, pool_sh)
+                     + ((rep,) if cfg.moe is not None else ()), **donate_kw)
     return jitted, {"params": param_sh, "pools": pool_sh}
 
 

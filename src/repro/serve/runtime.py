@@ -146,6 +146,20 @@ class ServeEngine:
         # serve.kernels.* counters pin it exactly)
         from repro.kernels import ops
         self.kernel_path = ops.resolve_paged_path(scfg.kernels)
+        # MoE configs: the step programs also return how many routes each
+        # held expert of each MoE layer took (fetched with the step's
+        # tokens; this step's arrays stay in ``moe_step_routes``)
+        mo = getattr(cfg, "moe", None)
+        self._moe = mo is not None
+        self.moe_step_routes: List[np.ndarray] = []
+        if self._moe:
+            m = self.obs.metrics
+            m.gauge("serve.moe.experts_held").set(mo.held)
+            m.gauge("serve.moe.experts_total").set(mo.num_experts)
+            m.counter("serve.moe.routes_held")
+            m.counter("serve.moe.routes")
+            self._moe_layers = sum(1 for _, f in cfg.block_kinds()
+                                   if f == "moe")
         self._decode_step, _ = E.make_paged_serve_step(
             cfg, self.mesh, self.plan, block_size=scfg.block_size,
             pool_tree=self.pool.state, donate=True, moe_dispatch=moe_dispatch,
@@ -438,7 +452,7 @@ class ServeEngine:
         # the prefill dispatch: it ends once the call is enqueued
         with tr.span("serve.prefill", track="engine", rows=len(reqs),
                      bucket=Pb, rids=[r.rid for r in reqs], step=step):
-            logits, self.pool.state = self._prefill_step(
+            logits, self.pool.state, *routes = self._prefill_step(
                 self.params, jnp.asarray(toks), jnp.asarray(starts),
                 jnp.asarray(limits), jnp.asarray(slots), self.pool.state,
                 jnp.asarray(tables))
@@ -448,6 +462,9 @@ class ServeEngine:
         self.obs.metrics.counter("serve.prefill_chunks").inc(len(reqs))
         # the first sample waits for the prefill program to finish
         with tr.span("serve.prefill.first_tokens", track="engine", step=step):
+            if routes:
+                self._count_routes(np.asarray(routes[0]),
+                                   sum(n for _, _, n in meta))
             for i, req, n in meta:
                 self.scheduler.on_prefill_chunk(req, n)
                 if req.prefill_done == req.prompt_len:
@@ -531,6 +548,7 @@ class ServeEngine:
         ``.wait``), ``serve.commit`` and ``serve.gauges``."""
         tr = self.obs.trace
         n = self.scheduler.steps                # this iteration's index
+        self.moe_step_routes = []
         with tr.step_span("serve.step", n, track="engine") as root:
             with tr.span("serve.schedule", track="engine", step=n):
                 plan = self.scheduler.schedule()
@@ -600,7 +618,7 @@ class ServeEngine:
         with tr.span("serve.decode", track="engine", runners=len(runners),
                      step=step):
             with tr.span("serve.decode.dispatch", track="engine", step=step):
-                logits, self.pool.state = self._decode_step(
+                logits, self.pool.state, *routes = self._decode_step(
                     self.params, jnp.asarray(tokens), jnp.asarray(positions),
                     self.pool.state, jnp.asarray(tables),
                     jnp.asarray(slot_mask))
@@ -608,11 +626,12 @@ class ServeEngine:
                 if all(r.temperature <= 0 and not r.capture_logprobs
                        for r in runners):
                     # batched greedy: one device op + one transfer for the
-                    # whole batch instead of a sync per seated slot
-                    nxt = np.asarray(jnp.argmax(
+                    # whole batch (and the route counts) instead of a sync
+                    # per seated slot
+                    nxt, *routes = jax.device_get([jnp.argmax(
                         logits[:, -1, :self.cfg.vocab_size].astype(
                             jnp.float32),
-                        axis=-1))
+                        axis=-1)] + routes)
                     picks = {r.slot: int(nxt[r.slot]) for r in runners}
                 elif all(r.temperature > 0 for r in runners):
                     # batched stochastic (the RL rollout hot path)
@@ -621,6 +640,8 @@ class ServeEngine:
                 else:
                     picks = {r.slot: self._sample(logits[r.slot, -1], r)
                              for r in runners}
+                if routes:
+                    self._count_routes(np.asarray(routes[0]), len(runners))
         events = []
         with tr.span("serve.commit", track="engine", step=step):
             for r in runners:
@@ -629,6 +650,15 @@ class ServeEngine:
                 self.tokens_generated += 1
                 events.append((r.rid, tok))
         return events
+
+    def _count_routes(self, routes: np.ndarray, tokens: int) -> None:
+        """One step program's held-expert route counts (n_moe_layers,
+        held), for ``tokens`` real tokens routed top-k in every MoE layer."""
+        self.moe_step_routes.append(routes)
+        m = self.obs.metrics
+        m.counter("serve.moe.routes_held").inc(int(routes.sum()))
+        m.counter("serve.moe.routes").inc(
+            tokens * self.cfg.moe.top_k * self._moe_layers)
 
     def _set_gauges(self) -> None:
         """Occupancy snapshot after an engine iteration (pool / archive /
@@ -744,4 +774,11 @@ class ServeEngine:
             "queue_wait_p50_s": qw.percentile(50),
             "recompiles": self.obs.recompiles(),
         })
+        if self._moe:
+            s.update({
+                "moe_routes_held": m.counter("serve.moe.routes_held").value,
+                "moe_routes": m.counter("serve.moe.routes").value,
+                "moe_experts_held": m.gauge("serve.moe.experts_held").value,
+                "moe_experts_total": m.gauge("serve.moe.experts_total").value,
+            })
         return s

@@ -142,3 +142,31 @@ def test_moe_active_params_smaller():
                  "moonshot-v1-16b-a3b"):
         cfg = get_config(arch)
         assert cfg.active_param_count() < 0.35 * cfg.param_count()
+
+
+def test_yarn_frequencies_and_scale_for_deepseek_v2_lite():
+    """DeepSeek-V2-Lite's YaRN over its 64 rope dims (factor 40 over 4,096
+    original positions, beta 32 / 1): dims below 2*10 keep their
+    frequency, from 2*23 on they are divided by 40, linear between; cos
+    and sin keep magnitude 1 (mscale = mscale_all_dim), and the softmax
+    scale is 192**-0.5 * (0.1 * 0.707 * ln 40 + 1)**2."""
+    import math
+
+    import numpy as np
+
+    from repro.models.common import rope_freqs, rope_magnitude, yarn_mscale
+    from repro.models.mla import softmax_scale
+
+    cfg = get_config("deepseek-v2-lite-16b")
+    rs = cfg.rope_scaling
+    plain = rope_freqs(64, 10000.0)
+    yarn = rope_freqs(64, 10000.0, rs)
+    np.testing.assert_allclose(yarn[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(yarn[23:], plain[23:] / 40, rtol=1e-6)
+    ramp = (16 - 10) / (23 - 10)
+    assert yarn[16] == pytest.approx(plain[16] * (1 - ramp)
+                                     + plain[16] / 40 * ramp, rel=1e-6)
+    assert rope_magnitude(rs) == 1.0
+    assert yarn_mscale(40, 0.707) == pytest.approx(1.26081, abs=1e-5)
+    assert softmax_scale(cfg) == pytest.approx(
+        192 ** -0.5 * (0.1 * 0.707 * math.log(40) + 1) ** 2)

@@ -317,6 +317,58 @@ def test_pool_gauges_set_once_at_build(qwen_f32):
     assert "serve_pool_leaves_sliced 0" in text
 
 
+@pytest.mark.parametrize("held", [0, 2])
+def test_moe_route_counters(held):
+    """An MoE config's step programs return each held expert's routes,
+    fetched with the step's tokens: ``serve.moe.routes_held`` adds them up
+    and ``serve.moe.routes`` counts every route of the real tokens (top-k
+    per token per MoE layer), so holding every expert makes the two equal
+    and a share takes fewer.  The expert gauges are set at build; all of
+    them are in ``stats()`` and the Prometheus dump.  A config without MoE
+    has none of them."""
+    base = get_config("deepseek-v2-lite-16b").reduced()
+    cfg = dataclasses.replace(base, dtype="float32", moe=dataclasses.replace(
+        base.moe, experts_held=held))
+    params = M.init_model(cfg, jax.random.PRNGKey(0))
+    scfg = ServeConfig(block_size=4, num_blocks=40, max_blocks_per_req=8,
+                       max_slots=2, prefill_chunk=4)
+    serve = HyperServe(cfg, params, serve_cfg=scfg)
+    m = serve.obs().metrics
+    assert m.gauge("serve.moe.experts_held").value == (held or 4)
+    assert m.gauge("serve.moe.experts_total").value == 4
+    prompts = [list(range(1, 7)), list(range(3, 14))]
+    for p in prompts:
+        serve.submit(p, 4)
+    serve.join()
+    st = serve.stats()
+    layers = cfg.num_layers - cfg.moe.first_k_dense
+    # every prompt token and every generated token but the last is a
+    # step program's real token
+    routes = (sum(len(p) for p in prompts) + 2 * 3) * 2 * layers
+    assert st["moe_routes"] == routes
+    if held:
+        assert 0 < st["moe_routes_held"] < routes
+    else:
+        assert st["moe_routes_held"] == routes
+    assert (st["moe_experts_held"], st["moe_experts_total"]) == (held or 4, 4)
+    text = m.dump_prometheus()
+    for name in ("serve_moe_routes_held", "serve_moe_routes",
+                 "serve_moe_experts_held", "serve_moe_experts_total"):
+        assert f"\n{name} " in text
+
+
+def test_no_moe_counters_without_moe(qwen_f32):
+    cfg, params = qwen_f32
+    serve = HyperServe(cfg, params, serve_cfg=ServeConfig(
+        block_size=4, num_blocks=16, max_blocks_per_req=4, max_slots=2,
+        prefill_chunk=4))
+    serve.submit(list(range(1, 6)), 3)
+    serve.join()
+    assert not [k for k in serve.stats() if k.startswith("moe_")]
+    assert "serve_moe" not in serve.obs().metrics.dump_prometheus()
+    assert serve.engine.moe_step_routes == []
+
+
 def test_stream_final_meta_lifecycle_record(qwen_f32):
     cfg, params = qwen_f32
     scfg = ServeConfig(block_size=4, num_blocks=40, max_blocks_per_req=8,

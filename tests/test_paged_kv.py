@@ -283,7 +283,9 @@ def test_layer_offset_writes_each_layers_own_pages(kernels):
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 
-@settings(max_examples=40)
+# no deadline: the first example pays a one-off warm-up (~300 ms against
+# Hypothesis' 200 ms default) that the same example does not pay again
+@settings(max_examples=40, deadline=None)
 @given(st.data())
 def test_block_manager_invariants_random_ops(data):
     """Free-list conservation, refcounts, CoW and window-freeing semantics

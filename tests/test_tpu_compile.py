@@ -147,6 +147,51 @@ def test_paged_steps_update_pool_in_place(one_chip, program, monkeypatch):
     assert {op for op, _ in moved} <= {"fusion", "scatter"}, moved
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_deepseek_v2_lite_paged_steps_compile(one_chip, program, monkeypatch):
+    """DeepSeek-V2-Lite's paged step programs at published widths, YaRN
+    and the published gate, one chip's share of 8 routed experts, 32 seats
+    at 4,096 context (a pool of 8,193 latent pages), with the route counts
+    the runtime fetches.  Depth is cut to 2 layers, the dense one and one
+    MoE layer, to keep the compile short: the layer loop compiles each
+    distinct layer once whatever the depth."""
+    monkeypatch.setattr(ops, "_MODE", "pallas")   # the chip's lowering
+    base = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(base, num_layers=2, moe=dataclasses.replace(
+        base.moe, experts_held=8))
+    seats, ctx = 32, 4096
+    pcfg = PagedKVConfig(block_size=BS, num_blocks=seats * ctx // BS + 1,
+                         max_blocks_per_req=ctx // BS, dtype="bfloat16")
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    arg = lambda shape, dt=I32: jax.ShapeDtypeStruct(shape, dt,
+                                                      sharding=one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_model(cfg, jax.random.PRNGKey(0))))
+    assert params["seg1"][0]["ffn"]["w_gate"].shape == (1, 8, 2048, 1408)
+    assert params["seg1"][0]["ffn"]["router"].shape == (1, 2048, 64)
+    pool = on_chip(jax.eval_shape(
+        lambda: StatePool(cfg, pcfg, num_slots=seats).state))
+    Wt = pcfg.max_blocks_per_req
+    kw = dict(block_size=BS, kernels="fused", moe_dispatch="ragged")
+    if program == "decode":
+        step, _ = E.make_paged_serve_step(cfg, None, None, **kw)
+        args = (params, arg((seats, 1)), arg((seats,)), pool,
+                arg((seats, Wt)), arg((seats,), jnp.bool_))
+    else:
+        step, _ = E.make_paged_prefill_step(cfg, None, None, **kw)
+        args = (params, arg((4, 128)), arg((4,)), arg((4,)), arg((4,)),
+                pool, arg((4, Wt)))
+    compiled = step.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "grouped_matmul" in hlo
+    if program == "decode":
+        assert "paged_mla_decode_attention" in hlo
+    routes = jax.eval_shape(step, *args)[-1]
+    assert routes.shape == (1, 8) and routes.dtype == jnp.int32
+
+
 @pytest.mark.parametrize("cache,window", [(544, None), (544, 128),
                                           (513, None)])
 def test_dense_decode_compiles(one_chip, cache, window):
