@@ -130,21 +130,24 @@ def attn_prefill(p, x, positions, cfg, *, window: Optional[int] = None):
     return out.reshape(B, S, -1) @ p["wo"], cache
 
 
-def _layer_pages(kv, layer):
-    """Layer ``layer``'s pages inside the stacked lane-dense pool.
+def layer_pages(kv, layer):
+    """Layer ``layer``'s pages inside a stacked paged pool.
 
-    ``kv``: {"k","v"} leaves (L, N_blocks, block, KV*hd).  Returns the
-    leaves viewed as one (L*N_blocks, block, KV*hd) pool (merging the two
-    leading dims is a bitcast, so the view is the pool itself) and the
-    page offset ``layer * N_blocks`` that maps the layer's block ids,
-    its null block 0 included, into that view.
+    ``kv``: a dict of pool leaves that share their leading dims,
+    (L, N_blocks, block, ...) — attention's lane-dense {"k","v"}, MLA's
+    {"ckv","krope"} latents.  Returns every leaf viewed as one
+    (L*N_blocks, block, ...) pool (merging the two leading dims is a
+    bitcast, so the view is the pool itself) and the page offset
+    ``layer * N_blocks`` that maps the layer's block ids, its null block
+    0 included, into that view.
     """
-    L, N = kv["k"].shape[:2]
+    L, N = next(iter(kv.values())).shape[:2]
     flat = {n: a.reshape((L * N,) + a.shape[2:]) for n, a in kv.items()}
     return flat, layer * N
 
 
-def _unflatten(flat, like):
+def unflatten_pages(flat, like):
+    """:func:`layer_pages`' view back in the stacked shapes of ``like``."""
     return {n: flat[n].reshape(like[n].shape) for n in flat}
 
 
@@ -158,7 +161,7 @@ def attn_decode_paged(p, x, positions, cfg, kv, block_tables, *, layer,
     is at a different position).  ``kv``: {"k","v"} pool leaves of EVERY
     layer, (L, N_blocks, block, KV*hd) — lane-dense pages, updated in
     place: the token scatter and the attention read layer ``layer``
-    (traced) through :func:`_layer_pages`' view, so no layer slice or
+    (traced) through :func:`layer_pages`' view, so no layer slice or
     pool copy is ever made.  ``block_tables``: (B, W) int32 layer-local
     block ids; row padding entries point at the null block and are never
     unmasked.
@@ -175,7 +178,7 @@ def attn_decode_paged(p, x, positions, cfg, kv, block_tables, *, layer,
     B = x.shape[0]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     q, k, v = _qkv(p, x, cfg, positions[:, None])
-    flat, base = _layer_pages(kv, layer)
+    flat, base = layer_pages(kv, layer)
     tables = block_tables + base
     bidx = jnp.take_along_axis(
         tables, (positions // block_size)[:, None], axis=1)[:, 0]
@@ -193,7 +196,7 @@ def attn_decode_paged(p, x, positions, cfg, kv, block_tables, *, layer,
         v_seq = v_pool[tables].reshape(B, W * block_size, KV, hd)
         out = ops.decode_attention(q, k_seq, v_seq, lengths, window=window)
     y = out.reshape(B, 1, H * hd) @ p["wo"]
-    return y, _unflatten({"k": k_pool, "v": v_pool}, kv)
+    return y, unflatten_pages({"k": k_pool, "v": v_pool}, kv)
 
 
 def paged_chunk_indices(positions, limits, block_tables, *, block_size: int):
@@ -251,7 +254,7 @@ def attn_prefill_paged(p, x, starts, limits, cfg, kv, block_tables, *,
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     positions = starts[:, None] + jnp.arange(C)[None, :]     # (P, C)
     q, k, v = _qkv(p, x, cfg, positions)
-    flat, base = _layer_pages(kv, layer)
+    flat, base = layer_pages(kv, layer)
     tables = block_tables + base
     bidx, off, _ = paged_chunk_indices(positions, limits, block_tables,
                                        block_size=block_size)
@@ -269,7 +272,7 @@ def attn_prefill_paged(p, x, starts, limits, cfg, kv, block_tables, *,
         v_seq = v_pool[tables].reshape(P, W * block_size, KV, hd)
         out = flash_rows(q, k_seq, v_seq, starts, window=window)
     y = out.reshape(P, C, H * hd) @ p["wo"]
-    return y, _unflatten({"k": k_pool, "v": v_pool}, kv)
+    return y, unflatten_pages({"k": k_pool, "v": v_pool}, kv)
 
 
 def attn_decode(p, x, pos, cfg, cache, *, window: Optional[int] = None):

@@ -80,8 +80,9 @@ class MixerSpec:
     fused_hooks: Tuple[str, ...] = ()
     # True: the serving hooks take EVERY layer's stacked leaves (L, ...)
     # plus a traced ``layer`` index and update that layer in place (paged
-    # attention).  False: they take one layer's leaves, which
-    # decode_layer / prefill_layer slice out and write back.
+    # attention and MLA latents).  False: they take one layer's leaves,
+    # which decode_layer / prefill_layer slice out and write back (SSD
+    # and RG-LRU slot state).
     in_place: bool = False
 
     def window(self, cfg) -> Optional[int]:
@@ -365,16 +366,18 @@ register_mixer(MixerSpec(
         mla_mod.init_mla_cache(cfg, batch, eff_len, dtype),
     init_state=lambda cfg, *, num_blocks, block_size, num_slots, dtype:
         mla_mod.init_mla_pool(cfg, num_blocks, block_size, dtype),
-    decode_paged=lambda p, h, positions, cfg, state, tables, *, block_size,
-        window, slot_mask=None, kernels="composed": mla_mod.mla_decode_paged(
-            p["attn"], h, positions, cfg, state, tables,
+    decode_paged=lambda p, h, positions, cfg, state, tables, *, layer,
+        block_size, window, slot_mask=None, kernels="composed":
+        mla_mod.mla_decode_paged(
+            p["attn"], h, positions, cfg, state, tables, layer=layer,
             block_size=block_size, kernels=kernels),
     prefill_paged=lambda p, h, starts, limits, slots, cfg, state, tables, *,
-        block_size, window, kernels="composed":
+        layer, block_size, window, kernels="composed":
         mla_mod.mla_prefill_chunk_paged(
-            p["attn"], h, starts, limits, cfg, state, tables,
+            p["attn"], h, starts, limits, cfg, state, tables, layer=layer,
             block_size=block_size, kernels=kernels),
     fused_hooks=("decode",),
+    in_place=True,
 ))
 
 

@@ -11,6 +11,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+from repro.models.attention import (flash_rows, full_attention, layer_pages,
+                                    paged_chunk_indices, unflatten_pages)
 from repro.models.common import (apply_rope, dense_init, dtype_of, rms_norm,
                                  yarn_mscale)
 
@@ -73,7 +76,6 @@ def mla_forward(p, x, positions, cfg, *, window=None, return_cache=False):
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (B, S, H, m.qk_rope_head_dim))], axis=-1)
     scale = softmax_scale(cfg)
-    from repro.models.attention import full_attention
     out = full_attention(q, k, v, window=window, scale=scale)
     y = out.reshape(B, S, H * m.v_head_dim) @ p["wo"]
     if return_cache:
@@ -102,15 +104,19 @@ def init_mla_pool(cfg, num_blocks: int, block_size: int, dtype):
     }
 
 
-def mla_decode_paged(p, x, positions, cfg, kv, block_tables, *,
+def mla_decode_paged(p, x, positions, cfg, kv, block_tables, *, layer,
                      block_size: int, kernels: str = "composed"):
     """Absorbed-matmul decode against the paged latent pool (HyperServe).
 
     x: (B, 1, D) one token per slot; ``positions``: (B,) per-slot absolute
-    write positions; ``kv``: {"ckv","krope"} pool leaves (N_blocks, block,
-    R) / (N_blocks, block, rope); ``block_tables``: (B, W).  Gathered rows
-    are indexed by absolute position, exactly like the dense latent cache,
-    so the score/readout math is identical to :func:`mla_decode`.
+    write positions; ``kv``: {"ckv","krope"} pool leaves of EVERY layer,
+    (L, N_blocks, block, R) / (L, N_blocks, block, rope), updated in
+    place: the token scatter and the attention read layer ``layer``
+    (traced) through :func:`~repro.models.attention.layer_pages`' view,
+    as paged attention does; ``block_tables``: (B, W) layer-local block
+    ids.  Gathered rows are indexed by absolute position, exactly like the
+    dense latent cache, so the score/readout math is identical to
+    :func:`mla_decode`.
 
     ``kernels="fused"`` lowers the latent attention to the
     block-table-walking Pallas kernel (``W_uk`` absorbed into the query
@@ -121,27 +127,27 @@ def mla_decode_paged(p, x, positions, cfg, kv, block_tables, *,
     B = x.shape[0]
     H = cfg.num_heads
     q_nope, q_rope, c_new, kr_new = _latents(p, x, positions[:, None], cfg)
-
+    flat, base = layer_pages(kv, layer)
+    tables = block_tables + base
     bidx = jnp.take_along_axis(
-        block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
+        tables, (positions // block_size)[:, None], axis=1)[:, 0]
     off = positions % block_size
-    ckv_pool = kv["ckv"].at[bidx, off].set(c_new[:, 0])
-    krope_pool = kv["krope"].at[bidx, off].set(kr_new[:, 0])
+    ckv_pool = flat["ckv"].at[bidx, off].set(c_new[:, 0])
+    krope_pool = flat["krope"].at[bidx, off].set(kr_new[:, 0])
 
     w_uk = p["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)       # (B,H,R)
     scale = softmax_scale(cfg)
     if kernels == "fused":
-        from repro.kernels import ops
         o_lat = ops.paged_mla_decode_attention(
-            q_lat, q_rope[:, 0], ckv_pool, krope_pool, block_tables,
+            q_lat, q_rope[:, 0], ckv_pool, krope_pool, tables,
             (positions + 1).astype(jnp.int32), block_size=block_size,
             scale=scale)
     else:
-        W = block_tables.shape[1]
+        W = tables.shape[1]
         S = W * block_size
-        ckv = ckv_pool[block_tables].reshape(B, S, m.kv_lora_rank)
-        krope = krope_pool[block_tables].reshape(B, S, m.qk_rope_head_dim)
+        ckv = ckv_pool[tables].reshape(B, S, m.kv_lora_rank)
+        krope = krope_pool[tables].reshape(B, S, m.qk_rope_head_dim)
         s = (jnp.einsum("bhr,bsr->bhs", q_lat.astype(jnp.float32),
                         ckv.astype(jnp.float32))
              + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32),
@@ -153,16 +159,19 @@ def mla_decode_paged(p, x, positions, cfg, kv, block_tables, *,
     w_uv = p["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
     o = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv.astype(jnp.float32))
     y = o.reshape(B, 1, H * m.v_head_dim).astype(x.dtype) @ p["wo"]
-    return y, {"ckv": ckv_pool, "krope": krope_pool}
+    return y, unflatten_pages({"ckv": ckv_pool, "krope": krope_pool}, kv)
 
 
 def mla_prefill_chunk_paged(p, x, starts, limits, cfg, kv, block_tables, *,
-                            block_size: int, kernels: str = "composed"):
+                            layer, block_size: int,
+                            kernels: str = "composed"):
     """One batched chunked-prefill step against the paged latent pool.
 
-    Mirrors :func:`repro.models.attention.attn_prefill_paged`: every
-    row's latents are written to that row's pages in one scatter (padding
-    positions >= the row's ``limit`` go to the null block), then each
+    Mirrors :func:`repro.models.attention.attn_prefill_paged`: ``kv``
+    holds every layer's latent leaves, as in :func:`mla_decode_paged`;
+    every row's latents are written to that row's pages of layer
+    ``layer`` in one in-place scatter (padding positions >= the row's
+    ``limit`` go to the layer's null block), then each
     row's chunk queries attend its gathered table in decompressed form —
     the same flash kernel and scale the dense prefill uses, with per-row
     ``q_offset=starts[r]`` causal masking.
@@ -174,21 +183,22 @@ def mla_prefill_chunk_paged(p, x, starts, limits, cfg, kv, block_tables, *,
     (``MixerSpec.fused_hooks`` records decode-only fusion for MLA).
     """
     del kernels
-    from repro.models.attention import flash_rows, paged_chunk_indices
-
     m = cfg.mla
     P, C, _ = x.shape
     H = cfg.num_heads
     positions = starts[:, None] + jnp.arange(C)[None, :]     # (P, C)
     q_nope, q_rope, c_kv, k_rope = _latents(p, x, positions, cfg)
+    flat, base = layer_pages(kv, layer)
+    tables = block_tables + base
     bidx, off, _ = paged_chunk_indices(positions, limits, block_tables,
                                        block_size=block_size)
-    ckv_pool = kv["ckv"].at[bidx, off].set(c_kv)
-    krope_pool = kv["krope"].at[bidx, off].set(k_rope)
-    W = block_tables.shape[1]
+    bidx = bidx + base
+    ckv_pool = flat["ckv"].at[bidx, off].set(c_kv)
+    krope_pool = flat["krope"].at[bidx, off].set(k_rope)
+    W = tables.shape[1]
     S = W * block_size
-    ckv_seq = ckv_pool[block_tables].reshape(P, S, m.kv_lora_rank)
-    krope_seq = krope_pool[block_tables].reshape(P, S, m.qk_rope_head_dim)
+    ckv_seq = ckv_pool[tables].reshape(P, S, m.kv_lora_rank)
+    krope_seq = krope_pool[tables].reshape(P, S, m.qk_rope_head_dim)
 
     k_nope = (ckv_seq @ p["w_uk"]).reshape(P, S, H, m.qk_nope_head_dim)
     v = (ckv_seq @ p["w_uv"]).reshape(P, S, H, m.v_head_dim)
@@ -199,7 +209,7 @@ def mla_prefill_chunk_paged(p, x, starts, limits, cfg, kv, block_tables, *,
     scale = softmax_scale(cfg)
     out = flash_rows(q, k, v, starts, scale=scale)
     y = out.reshape(P, C, H * m.v_head_dim) @ p["wo"]
-    return y, {"ckv": ckv_pool, "krope": krope_pool}
+    return y, unflatten_pages({"ckv": ckv_pool, "krope": krope_pool}, kv)
 
 
 def mla_decode(p, x, pos, cfg, cache, *, window=None):

@@ -212,8 +212,8 @@ class StatePool:
         — O(1) dense recurrent state, one row per decode seat.
 
     The leading stacked-layer axis is what the model's layer scan
-    indexes: paged attention leaves are updated in place, every other
-    leaf is sliced per layer and written back
+    indexes: paged leaves (attention K/V, MLA latents) are updated in
+    place, slot leaves are sliced per layer and written back
     (:meth:`repro.models.mixers.MixerSpec.decode_layer`).  Construction
     resolves the config against the registry
     (:func:`repro.models.mixers.model_state_layout`) — an unregistered

@@ -318,13 +318,13 @@ def _ssd_attn_cfg():
 
 
 @pytest.mark.parametrize("kernels", ["composed", "fused"])
-@pytest.mark.parametrize("arch", ["ssd-attn", "deepseek-v2-lite-16b"])
+@pytest.mark.parametrize("arch", ["ssd-attn"])
 def test_sliced_leaves_keep_outputs(arch, kernels):
     """Leaves the step programs slice per layer and write back (SSD
-    state beside in-place attention pages in one carried segment; MLA
-    latents) serve token-identically to the sequential Generator, and
-    the pool gauges count them."""
-    cfg = _ssd_attn_cfg() if arch == "ssd-attn" else _family_cfg(arch)
+    state beside in-place attention pages in one carried segment) serve
+    token-identically to the sequential Generator, and the pool gauges
+    count them."""
+    cfg = _ssd_attn_cfg()
     scfg = ServeConfig(block_size=4, num_blocks=40, max_blocks_per_req=8,
                        max_slots=3, prefill_chunk=4, kernels=kernels)
     serve = _assert_parity(cfg, scfg,
@@ -332,7 +332,28 @@ def test_sliced_leaves_keep_outputs(arch, kernels):
                             list(range(5, 10))], [6, 4, 8])
     st = serve.stats()
     assert st["pool_leaves_sliced"] > 0
-    assert st["pool_leaves_in_place"] == (2 if arch == "ssd-attn" else 0)
+    assert st["pool_leaves_in_place"] == 2
+
+
+@pytest.mark.parametrize("kernels", ["composed", "fused"])
+@pytest.mark.parametrize("arch", ["deepseek-v2-lite-16b"])
+def test_in_place_latents_keep_outputs(arch, kernels):
+    """MLA latents updated in place through the layer-offset view serve
+    token-identically to the sequential Generator.  Three layers, so the
+    MoE segment stacks two and its second layer reads and writes at a
+    page offset; the gauges count every latent leaf in place."""
+    cfg = _family_cfg(arch, num_layers=3)
+    scfg = ServeConfig(block_size=4, num_blocks=40, max_blocks_per_req=8,
+                       max_slots=3, prefill_chunk=4, kernels=kernels)
+    serve = _assert_parity(cfg, scfg,
+                           [list(range(1, 9)), list(range(20, 33)),
+                            list(range(5, 10))], [6, 4, 8])
+    mla_subs = sum(sp.kind == "mla" for seg in serve.engine.layout.segments
+                   for sp in seg.specs)
+    assert mla_subs == 2                  # the dense layer, the MoE stack
+    st = serve.stats()
+    assert st["pool_leaves_sliced"] == 0
+    assert st["pool_leaves_in_place"] == 2 * mla_subs    # ckv, krope
 
 
 @pytest.mark.parametrize("kernels", ["composed", "fused"])

@@ -295,25 +295,35 @@ def test_stats_percentiles_and_interval_rate(qwen_f32):
     assert st3["tokens_per_sec"] > 0
 
 
-def test_pool_gauges_set_once_at_build(qwen_f32):
+@pytest.mark.parametrize("arch,in_place", [
+    ("qwen2-0.5b", 2),                  # k, v
+    ("deepseek-v2-lite-16b", 4),        # ckv, krope in each of 2 segments
+])
+def test_pool_gauges_set_once_at_build(qwen_f32, arch, in_place):
     """``serve.pool.leaves_in_place`` / ``.leaves_sliced`` count how the
-    step programs hold the pool (qwen2: k and v in place, nothing
-    sliced), set when the engine builds its steps, before any request,
-    and shown in ``stats()`` and the Prometheus dump."""
-    cfg, params = qwen_f32
+    step programs hold the pool (attention K/V and MLA latents in place,
+    nothing sliced), set when the engine builds its steps, before any
+    request, and shown in ``stats()`` and the Prometheus dump."""
+    if arch == "qwen2-0.5b":
+        cfg, params = qwen_f32
+    else:
+        cfg = dataclasses.replace(get_config(arch).reduced(),
+                                  dtype="float32")
+        params = M.init_model(cfg, jax.random.PRNGKey(0))
     scfg = ServeConfig(block_size=4, num_blocks=16, max_blocks_per_req=4,
                        max_slots=2, prefill_chunk=4)
     serve = HyperServe(cfg, params, serve_cfg=scfg)
     m = serve.obs().metrics
-    assert m.gauge("serve.pool.leaves_in_place").value == 2
+    assert m.gauge("serve.pool.leaves_in_place").value == in_place
     assert m.gauge("serve.pool.leaves_sliced").value == 0
     serve.submit(list(range(1, 6)), 3)
     serve.join()
     st = serve.stats()
-    assert (st["pool_leaves_in_place"], st["pool_leaves_sliced"]) == (2, 0)
+    assert (st["pool_leaves_in_place"], st["pool_leaves_sliced"]) == \
+        (in_place, 0)
     text = m.dump_prometheus()
     assert "# TYPE serve_pool_leaves_in_place gauge\n" \
-        "serve_pool_leaves_in_place 2" in text
+        f"serve_pool_leaves_in_place {in_place}" in text
     assert "serve_pool_leaves_sliced 0" in text
 
 

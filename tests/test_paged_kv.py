@@ -211,12 +211,13 @@ def _ssd_attn_hybrid():
 @pytest.mark.parametrize("arch,want", [
     ("qwen2-0.5b", (2, 0)),               # k, v in place
     ("granite-3-2b", (2, 0)),
-    ("deepseek-v2-lite-16b", (0, 4)),     # ckv, krope x 2 segments, sliced
+    ("deepseek-v2-lite-16b", (4, 0)),     # ckv, krope x 2 segments
     ("ssd-attn", (2, 2)),                 # k, v in place; state, conv sliced
 ])
 def test_pool_leaves_in_place_or_sliced(arch, want):
-    """Attention pages are lane-dense, (L, N, bs, KV*hd), and updated in
-    place; every other leaf is sliced per layer and written back."""
+    """Paged leaves are updated in place: attention pages lane-dense,
+    (L, N, bs, KV*hd), MLA latents (L, N, bs, R) / (.., rope); slot
+    leaves are sliced per layer and written back."""
     cfg = (_ssd_attn_hybrid() if arch == "ssd-attn"
            else get_config(arch).reduced())
     pcfg = PagedKVConfig(block_size=4, num_blocks=16)
@@ -224,10 +225,17 @@ def test_pool_leaves_in_place_or_sliced(arch, want):
     assert pool.leaf_counts() == want
     for seg in pool.layout.segments:
         for j, spec in enumerate(seg.specs):
-            if spec.in_place:
-                for a in pool.state[seg.name][j].values():
-                    assert a.shape == (seg.repeat, 16, 4, cfg.num_kv_heads
-                                       * cfg.resolved_head_dim)
+            if not spec.in_place:
+                continue
+            leaves = pool.state[seg.name][j]
+            if spec.kind == "mla":
+                widths = {"ckv": cfg.mla.kv_lora_rank,
+                          "krope": cfg.mla.qk_rope_head_dim}
+            else:
+                widths = dict.fromkeys(
+                    "kv", cfg.num_kv_heads * cfg.resolved_head_dim)
+            assert {n: a.shape for n, a in leaves.items()} == {
+                n: (seg.repeat, 16, 4, w) for n, w in widths.items()}
 
 
 def test_seat_prefill_caches_lands_heads_side_by_side():
@@ -275,6 +283,47 @@ def test_layer_offset_writes_each_layers_own_pages(kernels):
             .at[:, 0, 0].set(True)
         assert (written == want).all(), n
         assert not jnp.allclose(a[0, 7, 1], a[1, 7, 1])   # two layers
+
+
+@pytest.mark.parametrize("kernels", ["composed", "fused"])
+def test_mla_hooks_update_one_stacked_layer(kernels):
+    """MLA's paged decode and prefill hooks at ``layer=1`` of a stacked
+    three-layer latent pool give what the same hooks give on layer 1
+    sliced out by hand, and leave layers 0 and 2 bit-identical."""
+    from repro.models import mla
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b").reduced(),
+                              dtype="float32")
+    m = cfg.mla
+    p = mla.init_mla(cfg, jax.random.PRNGKey(0))
+    L, N, bs = 3, 10, 4
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    kv = {"ckv": jax.random.normal(ks[0], (L, N, bs, m.kv_lora_rank)),
+          "krope": jax.random.normal(ks[1], (L, N, bs, m.qk_rope_head_dim))}
+    one = {n: a[1:2] for n, a in kv.items()}         # layer 1, by hand
+    tables = jnp.asarray([[3, 7, 0], [5, 2, 9]], jnp.int32)
+    kw = dict(block_size=bs, kernels=kernels)
+    calls = {
+        "decode": lambda pool, layer: mla.mla_decode_paged(
+            p, jax.random.normal(ks[2], (2, 1, cfg.d_model)),
+            jnp.asarray([6, 9], jnp.int32), cfg, pool, tables, layer=layer,
+            **kw),
+        # row 1 runs past its limit: those positions go to the null block
+        "prefill": lambda pool, layer: mla.mla_prefill_chunk_paged(
+            p, jax.random.normal(ks[3], (2, 4, cfg.d_model)),
+            jnp.asarray([4, 0], jnp.int32), jnp.asarray([8, 3], jnp.int32),
+            cfg, pool, tables, layer=layer, **kw),
+    }
+    for name, call in calls.items():
+        y, new = call(kv, 1)
+        y_one, new_one = call(one, 0)
+        assert jnp.array_equal(y, y_one), name
+        for n in kv:
+            assert new[n].shape == kv[n].shape
+            assert jnp.array_equal(new[n][1], new_one[n][0]), (name, n)
+            assert not jnp.array_equal(new[n][1], kv[n][1]), (name, n)
+            for other in (0, 2):
+                assert jnp.array_equal(new[n][other], kv[n][other]), \
+                    (name, n, other)
 
 
 # ---------------------------------------------------------------------------

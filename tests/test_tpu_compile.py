@@ -92,6 +92,12 @@ _HLO_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([a-z][\w\-]*)\(")
 _PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
 
 
+def _lanes(line):
+    """Minor dim of an instruction's (first) result shape."""
+    return int(re.search(r"\[([\d,]*)\]", line.split(" = ", 1)[1])
+               .group(1).split(",")[-1])
+
+
 def _page_axis_ops(hlo, sizes):
     """``(opcode, line)`` of every instruction whose result has a dim in
     ``sizes`` (the pool's page axis, alone or merged with its layers)."""
@@ -104,19 +110,41 @@ def _page_axis_ops(hlo, sizes):
     return hits
 
 
+def _in_place_case(arch):
+    """``(cfg, seats, table width, step kwargs)`` of a model cut in depth
+    whose paged pool holds 8,193 pages (the benchmark cells')."""
+    if arch == "qwen2-0.5b":
+        return dataclasses.replace(QWEN, num_layers=2), B, W, {}
+    base = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(base, num_layers=3, moe=dataclasses.replace(
+        base.moe, experts_held=8))
+    return cfg, 32, 4096 // BS, dict(moe_dispatch="ragged")
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_paged_steps_update_pool_in_place(one_chip, program, monkeypatch):
-    """The real paged step programs, qwen2-0.5b cut to 2 layers over an
-    8,193-page pool (the chat cell's; like any served pool, too large for
-    VMEM): the only op over the pool that moves bytes is the token
-    scatter, in place (``aliasing_operands``).  No copy, slice or
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "deepseek-v2-lite"])
+def test_paged_steps_update_pool_in_place(one_chip, arch, program,
+                                          monkeypatch):
+    """The real paged step programs over an 8,193-page pool (like any
+    served pool, too large for VMEM): qwen2-0.5b cut to 2 layers (K/V
+    pages), DeepSeek-V2-Lite cut to 3, the dense layer and two MoE
+    layers (MLA latents; the MoE segment stacks two layers, so its
+    second reads at a page offset).  No dynamic-slice or
     dynamic-update-slice of a layer or of the whole pool, in the layer
-    loop or around it; the kernels read the scattered pool directly."""
+    loop or around it; the kernels read the scattered pool directly.
+
+    Over a lane-dense leaf (K/V pages, MLA's 512-lane ``ckv``) the only
+    op that moves bytes is the token scatter, in place
+    (``aliasing_operands``): no copy either.  MLA's 64-lane ``krope``
+    leaf is the exception: the TPU's default layout for a bf16 array
+    with a minor dim under 128 puts the page axis minor, and the kernel
+    reads pages row-major, so XLA converts the whole stacked leaf where
+    the program takes and returns it (a copy each way, once per call)."""
     monkeypatch.setattr(ops, "_MODE", "pallas")   # the chip's lowering
-    cfg = dataclasses.replace(QWEN, num_layers=2)
+    cfg, seats, Wt, kw = _in_place_case(arch)
     pages = 8193
     pcfg = PagedKVConfig(block_size=BS, num_blocks=pages,
-                         max_blocks_per_req=W, dtype="bfloat16")
+                         max_blocks_per_req=Wt, dtype="bfloat16")
     on_chip = lambda tree: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         tree)
@@ -125,26 +153,34 @@ def test_paged_steps_update_pool_in_place(one_chip, program, monkeypatch):
     params = on_chip(jax.eval_shape(
         lambda: M.init_model(cfg, jax.random.PRNGKey(0))))
     pool = on_chip(jax.eval_shape(
-        lambda: StatePool(cfg, pcfg, num_slots=B).state))
+        lambda: StatePool(cfg, pcfg, num_slots=seats).state))
+    stacked = {a.shape[0] for a in jax.tree.leaves(pool)}
+    assert max(stacked) == 2
     if program == "decode":
         step, _ = E.make_paged_serve_step(cfg, None, None, block_size=BS,
-                                          kernels="fused")
-        args = (params, arg((B, 1)), arg((B,)), pool, arg((B, W)),
-                arg((B,), jnp.bool_))
+                                          kernels="fused", **kw)
+        args = (params, arg((seats, 1)), arg((seats,)), pool,
+                arg((seats, Wt)), arg((seats,), jnp.bool_))
     else:
         step, _ = E.make_paged_prefill_step(cfg, None, None, block_size=BS,
-                                            kernels="fused")
+                                            kernels="fused", **kw)
         args = (params, arg((4, 128)), arg((4,)), arg((4,)), arg((4,)),
-                pool, arg((4, W)))
+                pool, arg((4, Wt)))
     hlo = step.lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
     moved = [(op, line) for op, line in
-             _page_axis_ops(hlo, {pages, cfg.num_layers * pages})
+             _page_axis_ops(hlo, {pages, 2 * pages})
              if op not in _PLUMBING]
-    fusions = [line for op, line in moved if op == "fusion"]
+    sliced = [line for op, line in moved
+              if "dynamic" in op + line.split(" = ")[0]]
+    assert not sliced, sliced
+    dense = [(op, line) for op, line in moved if _lanes(line) % 128 == 0]
+    fusions = [line for op, line in dense if op == "fusion"]
     assert fusions and all("aliasing_operands" in f for f in fusions), \
         fusions
-    assert {op for op, _ in moved} <= {"fusion", "scatter"}, moved
+    assert {op for op, _ in dense} <= {"fusion", "scatter"}, dense
+    if arch == "qwen2-0.5b":
+        assert len(dense) == len(moved)           # every leaf lane-dense
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
